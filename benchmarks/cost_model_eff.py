@@ -30,8 +30,7 @@ def bench_attention_candidates():
                                                 window=0),
         "attn_banded": lambda q, k, v: A.sdpa_banded(q, k, v, window=window),
         "attn_flash": lambda q, k, v: A.sdpa_flash(q, k, v, causal=True,
-                                                   window=window,
-                                                   interpret=True),
+                                                   window=window),
     }
     for seq in (128, 512, 1024):
         rng = np.random.RandomState(seq)

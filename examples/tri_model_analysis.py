@@ -32,13 +32,15 @@ from repro.stores import ColumnStore, GraphStore, TextStore, store_engines
 
 
 def build_social_data(rng, *, users=500, hashtags=128, tweets=20_000,
-                      vocab=256):
+                      vocab=256, doc_tokens=(3, 12)):
     """Synthetic social-media slice: a tweet table, a hashtag co-mention
-    graph, and the tweet-text corpus (one doc per tweet)."""
-    user = rng.randint(0, users, tweets).astype(np.int32)
+    graph, and the tweet-text corpus (one doc per tweet).  ``rng`` is a
+    ``numpy.random.Generator``.  Every step is vectorized, so the same
+    builder makes deployment-size stores (``chip_smoke.py``)."""
+    user = rng.integers(0, users, tweets, dtype=np.int32)
     tag = (rng.zipf(1.3, tweets) % hashtags).astype(np.int32)
     doc = np.arange(tweets, dtype=np.int32)
-    engagement = (rng.gamma(2.0, 12.0, tweets)).astype(np.float32)
+    engagement = rng.gamma(2.0, 12.0, tweets).astype(np.float32)
     table = ColumnStore({"user": user, "hashtag": tag, "doc": doc,
                          "engagement": engagement})
 
@@ -51,10 +53,14 @@ def build_social_data(rng, *, users=500, hashtags=128, tweets=20_000,
     graph = GraphStore.from_edges(t_sorted[:-1][sel], t_sorted[1:][sel],
                                   hashtags, symmetric=True)
 
-    lens = rng.randint(3, 12, tweets)
-    flat = (rng.zipf(1.4, int(lens.sum())) % vocab).astype(np.int64)
-    docs = np.split(flat, np.cumsum(lens)[:-1])
-    corpus = TextStore.from_docs(docs, vocab)
+    # tweet d holds lens[d] Zipf-distributed tokens; one posting per
+    # distinct (doc, term), its count the term frequency
+    lens = rng.integers(doc_tokens[0], doc_tokens[1], tweets)
+    terms = rng.zipf(1.4, int(lens.sum())) % vocab
+    key = np.repeat(np.arange(tweets, dtype=np.int64), lens) * vocab + terms
+    key, tf = np.unique(key, return_counts=True)
+    corpus = TextStore.from_postings(key // vocab, key % vocab, tf,
+                                     np.maximum(lens, 1), vocab)
     return table, graph, corpus
 
 
@@ -92,7 +98,7 @@ create analysis hashtag_pulse as {{
 
 
 def main():
-    rng = np.random.RandomState(0)
+    rng = np.random.default_rng(0)
     table, graph, corpus = build_social_data(rng)
     cat = standard_catalog()
     analysis = parse_adil(adil_script(table, graph, corpus), cat)
@@ -101,7 +107,7 @@ def main():
     print(fn.explain())
     print()
 
-    query = jnp.asarray(corpus.query_vector(rng.randint(0, 256, 6)))
+    query = jnp.asarray(corpus.query_vector(rng.integers(0, 256, 6)))
     score = fn({}, {"tweets": table.payload(), "g": graph.payload(),
                     "cx": corpus.payload(), "q": query})
     top = np.argsort(-np.asarray(score))[:10]

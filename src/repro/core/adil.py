@@ -16,6 +16,7 @@ logical :class:`~repro.core.ir.Plan` ready for the AWESOME pipeline.
 """
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -77,9 +78,23 @@ class Analysis:
         carries the size metadata the planner prices movement with).  The
         store stays tracked: its monotonic ``version`` is folded into the
         plan-cache key at compile time, so appending to a bound store
-        invalidates plans cached against its previous contents."""
+        invalidates plans cached against its previous contents.
+
+        A name already declared (an ADIL ``table`` / ``graph`` / ``corpus``
+        statement) binds in place: the store must match the declaration up
+        to its mesh placement, which the store's own (``with_shards``)
+        replaces at compile time."""
+        declared = self.plan.inputs.get(name)
+        if declared is None:
+            self._bound[name] = store
+            return self.input(name, store.type)
+        unplaced = dataclasses.replace(store.type, partitioning=None)
+        if unplaced != dataclasses.replace(declared, partitioning=None):
+            raise ValidationError(
+                f"bind {name!r}: store {store.type!r} does not match the "
+                f"declared {declared!r}")
         self._bound[name] = store
-        return self.input(name, store.type)
+        return Var(name, self)
 
     def store_versions(self) -> tuple:
         """The bound stores' ``(name, version)`` vector (stores without a

@@ -342,6 +342,30 @@ def _e_rel_group(in_types, attrs, syscat):
                   2.0 * _sum_bytes([t]) + out_b, 0.0)
 
 
+# The one-hot Pallas kernels (stores/graph_kernels.py, masked_kernels.py)
+# walk every (output block, input block) pair of their grid and contract a
+# full in-block x out-block one-hot tile at each step, so their work grows
+# with out-blocks x in-blocks, not with the input alone: one SpMV over 262k
+# nodes and 16M edges is ~3e7 grid steps, TF-IDF over 8M docs and 59M
+# postings ~4e9.  Priced as the MXU flops those contractions issue -- a
+# lower bound, since the one-hot tile itself is built on the VPU.  The
+# blocks mirror the kernels' defaults and clamps; a grid beyond int32 steps
+# cannot compile at all (graph_kernels.check_grid) and prices as infinite.
+ONEHOT_BLOCK_IN = 512
+ONEHOT_BLOCK_OUT = 256
+ONEHOT_MAX_GRID_STEPS = 2 ** 31 - 1
+
+
+def _onehot_grid_flops(in_rows, out_rows, lhs_rows=1):
+    bi = min(ONEHOT_BLOCK_IN, max(8, int(in_rows)))
+    bo = min(ONEHOT_BLOCK_OUT, max(128, int(out_rows)))
+    steps = math.ceil(max(int(in_rows), 1) / bi) * math.ceil(
+        max(int(out_rows), 1) / bo)
+    if steps > ONEHOT_MAX_GRID_STEPS:
+        return math.inf
+    return steps * 2.0 * lhs_rows * bi * bo
+
+
 def _graph_cost(g, passes, syscat, pallas=False):
     e, n = int(g.edges), int(g.nodes)
     flops = 2.0 * e * passes
@@ -349,6 +373,7 @@ def _graph_cost(g, passes, syscat, pallas=False):
     bts = passes * (e * 12.0 + n * 8.0)
     if pallas:
         bts /= 2  # frontier accumulator stays VMEM-resident per node block
+        flops += passes * _onehot_grid_flops(e, n)
     return OpCost(flops, bts, 0.0)
 
 
@@ -438,6 +463,9 @@ def _e_text_topk_skip(in_types, attrs, syscat):
     bts = float(c.bytesize()) * s + c.docs * 9.0 + blocks * 64.0
     if attrs.get("_impl_pallas"):
         bts /= 2     # doc-block accumulator stays VMEM-resident
+        # a posting block is skipped only when its whole keep mask is zero,
+        # which a scattered doc mask almost never gives: price every step
+        flops += _onehot_grid_flops(c.postings, c.docs)
     return OpCost(flops, bts, 0.0)
 
 
@@ -495,10 +523,12 @@ _STEP_IMPL = {"rel_scan": "rel_scan_col", "rel_filter": "rel_filter_col",
 def _e_rel_fused(in_types, attrs, syscat):
     total = OpCost()
     prev_t = None
+    agg_in = agg_attrs = None
     for op, step_attrs, srcs, out_t in attrs.get("chain", ()):
         step_ins = [prev_t if s == "prev" else
                     (in_types[int(s)] if int(s) < len(in_types) else None)
                     for s in srcs]
+        agg_in, agg_attrs = step_ins[0], step_attrs
         c = op_cost(_STEP_IMPL.get(op, op), step_ins, step_attrs, syscat)
         if prev_t is not None:
             # fused: the interior input was just produced in-engine; credit
@@ -510,6 +540,13 @@ def _e_rel_fused(in_types, attrs, syscat):
         prev_t = out_t
     if attrs.get("_impl_pallas"):
         total.bytes *= 0.75   # masked one-hot agg keeps partials in VMEM
+        if agg_attrs is not None:
+            # one (sums, counts) kernel pass per aggregate over the rows
+            # entering the terminal group-by
+            calls = max(1, len(agg_attrs.get("aggs", ())))
+            total.flops += calls * _onehot_grid_flops(
+                _capacity_rows(agg_in), agg_attrs.get("num_groups", 1),
+                lhs_rows=2)
     return total
 
 

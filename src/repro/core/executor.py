@@ -128,7 +128,6 @@ class ExecContext:
     aux: dict = field(default_factory=dict)   # positions, masks, memory, ...
     mesh: Optional[Any] = None
     rules: ShardingRules = ShardingRules()
-    interpret: bool = True          # pallas interpret mode (CPU container)
     tracer: Optional[Any] = None    # core.tracing.Tracer; None = fast path
     faults: Optional[Any] = None    # core.faults.FaultInjector; None = off
 
@@ -301,8 +300,7 @@ def _i_flash(ctx, args, node):
     q, k = _prep(ctx, node, q, k)
     _emit_kv(ctx, node, k, v)
     return A.sdpa_flash(q, k, v, causal=node.attrs.get("causal", True),
-                        window=node.attrs.get("window", 0) or 0,
-                        interpret=ctx.interpret)
+                        window=node.attrs.get("window", 0) or 0)
 
 
 @impl("out_proj_xla")
@@ -378,7 +376,6 @@ def _i_moe_gmm(ctx, args, node):
     a = node.attrs
     return X.moe_gmm(ctx.params_for(node), args[0], top_k=a["top_k"],
                      experts=a["experts"], act=a.get("act", "silu"),
-                     interpret=ctx.interpret,
                      constrain=ctx.constrain if a.get("pin_moe") else None)
 
 
@@ -393,8 +390,7 @@ def _i_wkv_xla(ctx, args, node):
 def _i_wkv_pl(ctx, args, node):
     a = node.attrs
     return R.rwkv_time_mix(ctx.params_for(node), args[0], heads=a["heads"],
-                           head_dim=a["head_dim"], use_kernel=True,
-                           interpret=ctx.interpret)
+                           head_dim=a["head_dim"], use_kernel=True)
 
 
 @impl("ssd_chunked_xla")
@@ -412,7 +408,7 @@ def _i_ssd_pl(ctx, args, node):
     cfg = {"embed": a["embed"], "state": a["state"],
            "expand": a.get("expand", 2), "head_dim": a["head_dim"]}
     return M.mamba2_block(ctx.params_for(node), args[0], cfg,
-                          use_kernel=True, interpret=ctx.interpret)
+                          use_kernel=True)
 
 
 @impl("rwkv_channel_mix")
@@ -647,7 +643,7 @@ class PlannedFunction:
     The planning product itself (logical_opt / candidates / concrete plan /
     choices / buffering / EXPLAIN trace) lives in the StagedPhysicalPlan —
     the unit the plan cache stores; this wrapper adds the runtime-only
-    bindings (mesh, sharding rules, interpret mode) plus legacy field access
+    bindings (mesh, sharding rules) plus legacy field access
     for existing callers."""
 
     logical: Plan
@@ -659,7 +655,6 @@ class PlannedFunction:
     syscat: SystemCatalog
     rules: ShardingRules
     mesh: Optional[Any] = None
-    interpret: bool = True
     plan_id: str = ""
     staged: Optional[Any] = None     # StagedPhysicalPlan
     faults: Optional[Any] = None     # core.faults.FaultInjector; None = off
@@ -668,11 +663,11 @@ class PlannedFunction:
 
     @classmethod
     def from_staged(cls, staged, syscat: SystemCatalog, *,
-                    rules: "ShardingRules" = None, mesh=None,
-                    interpret: bool = True) -> "PlannedFunction":
+                    rules: "ShardingRules" = None,
+                    mesh=None) -> "PlannedFunction":
         return cls(staged.logical, staged.pplan, staged.concrete,
                    staged.choices, staged.report, staged.buffering,
-                   syscat, rules or ShardingRules(), mesh, interpret,
+                   syscat, rules or ShardingRules(), mesh,
                    staged.plan_id, staged)
 
     def explain(self, analyze=False) -> str:
@@ -694,7 +689,7 @@ class PlannedFunction:
     def __call__(self, params, inputs: dict, aux: Optional[dict] = None):
         ctx = ExecContext(root=params, scope=params, aux=aux or {},
                           mesh=self.mesh, rules=self.rules,
-                          interpret=self.interpret, faults=self.faults)
+                          faults=self.faults)
         outs = run_plan(self.concrete, ctx, inputs)
         return outs if len(outs) > 1 else outs[0]
 
@@ -754,8 +749,7 @@ class PlannedFunction:
         run_aux["count_sink"] = sink
         ctx = ExecContext(root=params, scope=params, aux=run_aux,
                           mesh=self.mesh, rules=self.rules,
-                          interpret=self.interpret, tracer=tracer,
-                          faults=self.faults)
+                          tracer=tracer, faults=self.faults)
         t0 = time.perf_counter()
         try:
             with tracer.span("run", "run", plan_id=self.plan_id):
@@ -838,7 +832,6 @@ def plan_and_compile(logical: Plan, catalog: FunctionCatalog,
                      buffering: bool = False,
                      global_batch: int = 1,
                      rewrite_pipeline=None,
-                     interpret: bool = True,
                      cache=None,
                      pipeline=None,
                      plan_threads: int = 1,
@@ -873,4 +866,4 @@ def plan_and_compile(logical: Plan, catalog: FunctionCatalog,
                             cache=cache, feedback=feedback,
                             extra_key=extra_key)
     return PlannedFunction.from_staged(staged, syscat, rules=rules,
-                                       mesh=mesh, interpret=interpret)
+                                       mesh=mesh)

@@ -1235,11 +1235,38 @@ class HardwareSpec:
     vmem_bytes: float = 128 * 2 ** 20
 
 
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of interconnect over four links).
+HARDWARE_BY_KIND = {"TPU v5 lite": HardwareSpec()}
+
+# Plans made on a backend without a TPU (CPU tests, compile rehearsals) are
+# plans for this part.
+PLANNING_TARGET_KIND = "TPU v5 lite"
+
+
+def hardware_for(device=None) -> HardwareSpec:
+    """The hardware peaks the planner prices against on ``device`` (default:
+    the first attached device).  A TPU kind missing from
+    :data:`HARDWARE_BY_KIND` is an error, never a default."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return HARDWARE_BY_KIND[PLANNING_TARGET_KIND]
+    try:
+        return HARDWARE_BY_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware peaks for TPU kind {device.device_kind!r}; add "
+            f"them to HARDWARE_BY_KIND with their source") from None
+
+
 @dataclass(frozen=True)
 class SystemCatalog:
     """Registered 'stores' — here the mesh axes + hardware description."""
 
-    hardware: HardwareSpec = HardwareSpec()
+    hardware: HardwareSpec = field(default_factory=hardware_for)
     mesh_axes: tuple = ("data", "model")
     mesh_shape: tuple = (1, 1)
 

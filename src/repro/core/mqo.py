@@ -430,8 +430,7 @@ def mqo_run(planned, params, inputs: Mapping[str, Any], *,
                            params=params, input_keys=input_keys)
     hits, residual = split_at_frontier(pplan, keys, cache)
     ctx = ExecContext(root=params, scope=params, aux=aux or {},
-                      mesh=planned.mesh, rules=planned.rules,
-                      interpret=planned.interpret)
+                      mesh=planned.mesh, rules=planned.rules)
     env = dict(inputs)
     env.update(hits)
     env = run_plan_subset(pplan, ctx, env, residual)

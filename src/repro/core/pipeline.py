@@ -7,7 +7,7 @@ buffering.  This module makes each stage a registered, individually-timed
 **pipeline pass** over a shared :class:`PipelineContext`, and makes the
 product a :class:`StagedPhysicalPlan` with a stable content-hashed
 ``plan_id`` — the unit the plan cache stores and the executor binds to a
-runtime context (mesh / sharding rules / interpret mode).
+runtime context (mesh / sharding rules).
 
 Default pass order (Algorithm 1):
 
@@ -48,7 +48,7 @@ from .rewrite import rewrite_with_trace
 class PlanOptions:
     """Everything that changes *what plan comes out* for a given logical
     plan + catalogs.  Hashed into ``plan_id``; runtime-only bindings (mesh
-    object, sharding rules, interpret mode) deliberately live outside.
+    object, sharding rules) deliberately live outside.
 
     ``plan_threads`` parallelizes candidate generation per scan-group; it
     changes only planning wall time, never the chosen plan, so it is

@@ -10,9 +10,9 @@ decision and the per-pass trace).
 
 A cache hit skips the entire pass pipeline: repeated/bucketed workloads
 (serving buckets, re-built train steps, dry-run sweeps) rebind the cached
-staged plan to their runtime context (mesh / sharding rules / interpret
-mode) instead of replanning from scratch.  Staged plans are treated as
-immutable once cached; the executor never mutates them at call time.
+staged plan to their runtime context (mesh / sharding rules) instead of
+replanning from scratch.  Staged plans are treated as immutable once
+cached; the executor never mutates them at call time.
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@
 Accepts the framework-standard (B, S, H, D) layout, pads sequence lengths to
 block multiples (masked out in-kernel via the length arguments), transposes
 to the kernel's heads-major layout, and dispatches to the Pallas kernel —
-``interpret=True`` on CPU (this container), compiled on TPU.
+compiled on a TPU and interpreted elsewhere (``core.backend``).
 
 Differentiation: the kernel carries a ``custom_vjp`` whose backward is the
 VJP of the pure-jnp oracle (recompute-from-inputs).  On TPU the backward
@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...core.backend import pallas_interpret
 from .flash_attention import flash_attention_hmajor
 from .ref import mha_reference
 
@@ -32,9 +33,12 @@ def _pad_to(x, axis, mult):
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, sm_scale=None,
-                    block_q=128, block_k=128, interpret=True):
+                    block_q=128, block_k=128, interpret=None):
     """q: (B, Sq, H, D); k, v: (B, Skv, K, D) -> (B, Sq, H, D).
-    Differentiable (custom_vjp; backward = oracle VJP)."""
+    Differentiable (custom_vjp; backward = oracle VJP).  ``interpret=None``
+    follows the backend."""
+    if interpret is None:
+        interpret = pallas_interpret()
     fn = _diffable(bool(causal), int(window or 0),
                    float(sm_scale) if sm_scale is not None else None,
                    block_q, block_k, bool(interpret))
@@ -65,7 +69,7 @@ def _diffable(causal, window, sm_scale, block_q, block_k, interpret):
 
 
 def _forward(q, k, v, *, causal=True, window=0, sm_scale=None,
-             block_q=128, block_k=128, interpret=True):
+             block_q=128, block_k=128, interpret):
     b, sq, h, d = q.shape
     _, skv, kh, _ = k.shape
     bq = min(block_q, max(8, 1 << (sq - 1).bit_length()))

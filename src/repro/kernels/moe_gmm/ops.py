@@ -7,6 +7,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...core.backend import pallas_interpret
 from .moe_gmm import gmm as _gmm
 from .ref import gmm_reference
 
@@ -20,8 +21,11 @@ def _pad_axis(x, axis, mult):
     return jnp.pad(x, pad)
 
 
-def grouped_matmul(x, w, *, block=128, interpret=True):
-    """Differentiable (custom_vjp; backward = einsum-oracle VJP)."""
+def grouped_matmul(x, w, *, block=128, interpret=None):
+    """Differentiable (custom_vjp; backward = einsum-oracle VJP).
+    ``interpret=None`` follows the backend."""
+    if interpret is None:
+        interpret = pallas_interpret()
     return _diffable(block, bool(interpret))(x, w)
 
 
@@ -43,7 +47,7 @@ def _diffable(block, interpret):
     return f
 
 
-def _forward(x, w, *, block=128, interpret=True):
+def _forward(x, w, *, block=128, interpret):
     e, c, d = x.shape
     f = w.shape[-1]
     bc = min(block, max(8, c))

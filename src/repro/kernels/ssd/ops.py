@@ -8,12 +8,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...core.backend import pallas_interpret
 from .ref import ssd_reference
 from .ssd import ssd_hmajor
 
 
-def ssd(x, a, b, c, *, chunk=128, interpret=True):
-    """Differentiable (custom_vjp; backward = oracle VJP)."""
+def ssd(x, a, b, c, *, chunk=128, interpret=None):
+    """Differentiable (custom_vjp; backward = oracle VJP).
+    ``interpret=None`` follows the backend."""
+    if interpret is None:
+        interpret = pallas_interpret()
     return _diffable(chunk, bool(interpret))(x, a, b, c)
 
 
@@ -35,7 +39,7 @@ def _diffable(chunk, interpret):
     return f
 
 
-def _forward(x, a, b, c, *, chunk=128, interpret=True):
+def _forward(x, a, b, c, *, chunk=128, interpret):
     bs, t, h, p = x.shape
     n = b.shape[-1]
     ch = min(chunk, max(8, t))

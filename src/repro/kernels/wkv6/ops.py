@@ -8,12 +8,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...core.backend import pallas_interpret
 from .ref import wkv6_reference
 from .wkv6 import wkv6_hmajor
 
 
-def wkv6(r, k, v, w, u, *, chunk=128, interpret=True):
-    """Differentiable (custom_vjp; backward = oracle VJP)."""
+def wkv6(r, k, v, w, u, *, chunk=128, interpret=None):
+    """Differentiable (custom_vjp; backward = oracle VJP).
+    ``interpret=None`` follows the backend."""
+    if interpret is None:
+        interpret = pallas_interpret()
     return _diffable(chunk, bool(interpret))(r, k, v, w, u)
 
 
@@ -36,7 +40,7 @@ def _diffable(chunk, interpret):
     return f
 
 
-def _forward(r, k, v, w, u, *, chunk=128, interpret=True):
+def _forward(r, k, v, w, u, *, chunk=128, interpret):
     b, t, h, d = r.shape
     c = min(chunk, max(8, t))
     rem = (-t) % c

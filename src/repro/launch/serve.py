@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import get_config, get_smoke_config
+from ..core.backend import enable_compile_cache
 from ..core.executor import plan_and_compile
 from ..core.ir import SystemCatalog
 from ..models import build_model
@@ -111,6 +112,7 @@ def main(argv=None):
                     help="print one bucket's EXPLAIN report")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))

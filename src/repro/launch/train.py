@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import SHAPES, get_config, get_smoke_config
+from ..core.backend import enable_compile_cache
 from ..core.executor import plan_and_compile
 from ..core.ir import SystemCatalog
 from ..core.plan_cache import (default_plan_cache, load_plan_cache,
@@ -63,6 +64,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))

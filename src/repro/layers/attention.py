@@ -139,9 +139,8 @@ def sdpa_banded(q, k, v, *, window, causal=True):
     return out.astype(q.dtype)
 
 
-def sdpa_flash(q, k, v, *, causal=True, window=0, interpret=True):
-    return flash_attention(q, k, v, causal=causal, window=window,
-                           interpret=interpret)
+def sdpa_flash(q, k, v, *, causal=True, window=0):
+    return flash_attention(q, k, v, causal=causal, window=window)
 
 
 # --------------------------------------------------------------------------

@@ -62,7 +62,7 @@ def _causal_conv(x, w, conv_state=None):
     return jax.nn.silu(out), new_state
 
 
-def mamba2_block(p, x, cfg, *, use_kernel=False, interpret=True, state=None,
+def mamba2_block(p, x, cfg, *, use_kernel=False, state=None,
                  conv_state=None):
     """x: (B,T,E).  Decode mode when ``state`` is given: returns
     (y, new_state, new_conv_state)."""
@@ -94,7 +94,7 @@ def mamba2_block(p, x, cfg, *, use_kernel=False, interpret=True, state=None,
         y, new_state = ssd_reference(xs, a.astype(xs.dtype), bh, chh,
                                      initial_state=state)
     elif use_kernel:
-        y = ssd_kernel(xs, a.astype(xs.dtype), bh, chh, interpret=interpret)
+        y = ssd_kernel(xs, a.astype(xs.dtype), bh, chh)
         new_state = None
     else:
         # chunked jnp engine (matmul re-expression; state per chunk)

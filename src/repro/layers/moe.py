@@ -49,8 +49,7 @@ def _route(p, x, top_k):
 
 
 def moe_capacity_dispatch(p, x, *, top_k, experts, capacity_factor=2.0,
-                          act="silu", use_gmm=False, interpret=True,
-                          constrain=None):
+                          act="silu", use_gmm=False, constrain=None):
     """Row-grouped capacity dispatch.
 
     The scatter into expert buffers happens *per batch row* (the row dim is
@@ -92,12 +91,10 @@ def moe_capacity_dispatch(p, x, *, top_k, experts, capacity_factor=2.0,
         expert_in = constrain(expert_in, ("experts", "batch", None))
 
     if use_gmm:
-        up = grouped_matmul(expert_in, p["wi"].astype(x.dtype),
-                            interpret=interpret)
-        gate = grouped_matmul(expert_in, p["wg"].astype(x.dtype),
-                              interpret=interpret)
+        up = grouped_matmul(expert_in, p["wi"].astype(x.dtype))
+        gate = grouped_matmul(expert_in, p["wg"].astype(x.dtype))
         h = _ACTS[act](gate) * up
-        out = grouped_matmul(h, p["wo"].astype(x.dtype), interpret=interpret)
+        out = grouped_matmul(h, p["wo"].astype(x.dtype))
     else:
         up = jnp.einsum("xce,xef->xcf", expert_in, p["wi"].astype(x.dtype))
         gate = jnp.einsum("xce,xef->xcf", expert_in, p["wg"].astype(x.dtype))
@@ -125,25 +122,23 @@ def moe_capacity_dispatch(p, x, *, top_k, experts, capacity_factor=2.0,
 
 
 def moe_dense(p, x, *, top_k, experts, act="silu", capacity_factor=2.0,
-              interpret=True, constrain=None):
+              constrain=None):
     return moe_capacity_dispatch(p, x, top_k=top_k, experts=experts,
                                  capacity_factor=capacity_factor, act=act,
                                  constrain=constrain)
 
 
-def moe_dropping(p, x, *, top_k, experts, act="silu", interpret=True,
-                 constrain=None):
+def moe_dropping(p, x, *, top_k, experts, act="silu", constrain=None):
     return moe_capacity_dispatch(p, x, top_k=top_k, experts=experts,
                                  capacity_factor=1.0, act=act,
                                  constrain=constrain)
 
 
 def moe_gmm(p, x, *, top_k, experts, act="silu", capacity_factor=2.0,
-            interpret=True, constrain=None):
+            constrain=None):
     return moe_capacity_dispatch(p, x, top_k=top_k, experts=experts,
                                  capacity_factor=capacity_factor, act=act,
-                                 use_gmm=True, interpret=interpret,
-                                 constrain=constrain)
+                                 use_gmm=True, constrain=constrain)
 
 
 def moe_reference_dense(p, x, *, top_k, experts, act="silu"):

@@ -50,8 +50,8 @@ def _token_shift(x):
     return jnp.pad(x, [(0, 0), (1, 0), (0, 0)])[:, :-1]
 
 
-def rwkv_time_mix(p, x, *, heads, head_dim, use_kernel=False, interpret=True,
-                  last_x=None, state=None):
+def rwkv_time_mix(p, x, *, heads, head_dim, use_kernel=False, last_x=None,
+                  state=None):
     """x: (B, T, E).  When ``state``/``last_x`` are given (decode), runs the
     single-step recurrence and returns (y, new_last_x, new_state)."""
     b, t, e = x.shape
@@ -83,8 +83,7 @@ def rwkv_time_mix(p, x, *, heads, head_dim, use_kernel=False, interpret=True,
         y, new_state = wkv6_reference(rh, kh, vh, wh.astype(rh.dtype),
                                       p["u"], initial_state=state)
     elif use_kernel:
-        y = wkv6_kernel(rh, kh, vh, wh.astype(rh.dtype), p["u"],
-                        interpret=interpret)
+        y = wkv6_kernel(rh, kh, vh, wh.astype(rh.dtype), p["u"])
         new_state = None
     else:
         # chunked jnp engine: state materializes once per chunk, not per

@@ -111,7 +111,7 @@ class DegradePolicy:
     def replan(self, planned: PlannedFunction, lvl: int, *,
                cache=None) -> PlannedFunction:
         """The degraded variant of a compiled analytical function.  Same
-        runtime bindings (mesh / rules / interpret / faults); different —
+        runtime bindings (mesh / rules / faults); different —
         and provably different — plan id whenever anything was clamped.
         Returns ``planned`` unchanged when the level clamps nothing."""
         from ..core.pipeline import compile_staged
@@ -124,7 +124,7 @@ class DegradePolicy:
             cache=cache, extra_key=(("degrade_level", int(lvl)),))
         fn = PlannedFunction.from_staged(
             staged, planned.syscat, rules=planned.rules,
-            mesh=planned.mesh, interpret=planned.interpret)
+            mesh=planned.mesh)
         fn.faults = planned.faults
         self._observe(lvl, planned.plan_id, fn.plan_id, changes)
         return fn

@@ -12,16 +12,41 @@ exactly once — the bytes advantage the cost model credits the Pallas
 candidate with.
 
 The value gather ``x[src[e]] * w[e]`` happens *outside* the kernel (XLA
-gathers are fine); the kernel owns the scatter side only.
+gathers are fine); the kernel owns the scatter side only.  Like every
+Pallas kernel here, it compiles on a TPU and runs interpreted elsewhere
+(``interpret=None``, :func:`repro.core.backend.pallas_interpret`).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..core.backend import pallas_interpret
+
+# f32 contraction on the MXU: the default TPU precision rounds the value
+# operand to bfloat16, and these sums must match the f32 segment_sum
+_EXACT = jax.lax.Precision.HIGHEST
+
+# the TPU compiler counts grid steps in int32: a larger grid aborts the
+# process inside the compiler instead of raising
+MAX_GRID_STEPS = 2 ** 31 - 1
+
+
+def check_grid(grid, kernel: str) -> tuple:
+    """Refuse a one-hot grid the TPU compiler cannot index."""
+    steps = 1
+    for g in grid:
+        steps *= int(g)
+    if steps > MAX_GRID_STEPS:
+        raise ValueError(
+            f"{kernel}: grid {grid} has {steps} steps, beyond the "
+            f"{MAX_GRID_STEPS} a TPU kernel can index")
+    return grid
 
 
 def _scatter_add_kernel(dst_ref, val_ref, o_ref, acc_ref, *, block_n):
@@ -36,7 +61,8 @@ def _scatter_add_kernel(dst_ref, val_ref, o_ref, acc_ref, *, block_n):
     dst = dst_ref[...]                       # (1, E_blk) int32
     val = val_ref[...]                       # (1, E_blk) float32
     onehot = (dst[0][:, None] == node_ids[0][None, :]).astype(jnp.float32)
-    acc_ref[...] += jnp.dot(val, onehot, preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(val, onehot, precision=_EXACT,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(eb == pl.num_programs(1) - 1)
     def _fin():
@@ -47,7 +73,7 @@ def _scatter_add_kernel(dst_ref, val_ref, o_ref, acc_ref, *, block_n):
                    static_argnames=("num_nodes", "block_e", "block_n",
                                     "interpret"))
 def scatter_add_pallas(vals, dst, *, num_nodes: int, block_e: int = 512,
-                       block_n: int = 256, interpret: bool = True):
+                       block_n: int = 256, interpret: Optional[bool] = None):
     """``y[n] = sum over e with dst[e]==n of vals[e]`` for ``n < num_nodes``.
 
     Edge padding uses ``dst = -1`` (matches no node); node padding is
@@ -65,7 +91,7 @@ def scatter_add_pallas(vals, dst, *, num_nodes: int, block_e: int = 512,
                   constant_values=-1)[None, :]
     n_tot = num_nodes + n_pad
 
-    grid = (n_tot // bn, (e + e_pad) // be)
+    grid = check_grid((n_tot // bn, (e + e_pad) // be), "scatter_add")
     out = pl.pallas_call(
         functools.partial(_scatter_add_kernel, block_n=bn),
         grid=grid,
@@ -76,6 +102,6 @@ def scatter_add_pallas(vals, dst, *, num_nodes: int, block_e: int = 512,
         out_specs=pl.BlockSpec((1, bn), lambda nb, ebk: (0, nb)),
         out_shape=jax.ShapeDtypeStruct((1, n_tot), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(dst, vals)
     return out[0, :num_nodes]

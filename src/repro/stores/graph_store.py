@@ -162,16 +162,15 @@ def _spmv(g: dict, x, scatter: Optional[Callable] = None):
     return jax.ops.segment_sum(vals, g["indices"], num_segments=n)
 
 
-def _pallas_scatter(interpret: bool) -> Callable:
-    return lambda vals, dst, n: scatter_add_pallas(
-        vals, dst, num_nodes=n, interpret=interpret)
+def _pallas_scatter(vals, dst, n):
+    return scatter_add_pallas(vals, dst, num_nodes=n)
 
 
 def expand_frontier(g: dict, frontier, hops: int = 1,
-                    use_pallas: bool = False, interpret: bool = True):
+                    use_pallas: bool = False):
     """k-hop expansion: propagate frontier weight along edges ``hops``
     times.  One hop is exactly one SpMV."""
-    scatter = _pallas_scatter(interpret) if use_pallas else None
+    scatter = _pallas_scatter if use_pallas else None
     x = frontier.astype(jnp.float32)
     for _ in range(int(hops)):
         x = _spmv(g, x, scatter)
@@ -252,7 +251,7 @@ def expand_frontier_blockskip(g: dict, frontier, hops: int = 1,
 
 def pagerank(g: dict, iters: int = 10, damping: float = 0.85,
              personalization=None, use_pallas: bool = False,
-             interpret: bool = True, skip_first: bool = False,
+             skip_first: bool = False,
              block: int = 2048):
     """Damped power iteration with out-degree normalization.
 
@@ -263,7 +262,7 @@ def pagerank(g: dict, iters: int = 10, damping: float = 0.85,
     would contribute exactly ``+0.0``, so the result is bitwise identical
     to the dense iteration; later iterations (whose rank vector is dense
     after one propagation) stay on the dense SpMV."""
-    scatter = _pallas_scatter(interpret) if use_pallas else None
+    scatter = _pallas_scatter if use_pallas else None
     n = g["indptr"].shape[0] - 1
     if personalization is None:
         p0 = jnp.full((n,), 1.0 / n, jnp.float32)

@@ -18,11 +18,19 @@ reduction side only), mirroring ``scatter_add_pallas``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..core.backend import pallas_interpret
+from .graph_kernels import check_grid
+
+# f32 contractions on the MXU: the default TPU precision rounds operands to
+# bfloat16, which would round values (and join-probe row indices above 256)
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 # --------------------------------------------------------------------------
@@ -50,7 +58,7 @@ def _masked_tfidf_kernel(doc_ref, qidf_ref, tf_ref, dl_ref, keep_ref,
         val = qidf_ref[...] * tf_ref[...] / dl_ref[...] * keep
         doc = doc_ref[...]
         onehot = (doc[0][:, None] == doc_ids[0][None, :]).astype(jnp.float32)
-        acc_ref[...] += jnp.dot(val, onehot,
+        acc_ref[...] += jnp.dot(val, onehot, precision=_EXACT,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(eb == pl.num_programs(1) - 1)
@@ -63,7 +71,7 @@ def _masked_tfidf_kernel(doc_ref, qidf_ref, tf_ref, dl_ref, keep_ref,
                                     "interpret"))
 def masked_tfidf_pallas(doc_ids, qidf_t, tf, dl_t, keep, *, n_docs: int,
                         block_e: int = 512, block_d: int = 256,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """``score[d] = Σ_{postings e: doc[e]==d, keep[e]>0} qidf_t[e]·tf[e]/
     dl_t[e]`` — masked TF-IDF scores over pre-gathered posting features.
 
@@ -89,7 +97,7 @@ def masked_tfidf_pallas(doc_ids, qidf_t, tf, dl_t, keep, *, n_docs: int,
     keep_p = prep(keep.astype(jnp.float32))
     d_tot = n_docs + d_pad
 
-    grid = (d_tot // bd, (e + e_pad) // be)
+    grid = check_grid((d_tot // bd, (e + e_pad) // be), "masked_tfidf")
     espec = pl.BlockSpec((1, be), lambda db, ebk: (0, ebk))
     out = pl.pallas_call(
         functools.partial(_masked_tfidf_kernel, block_d=bd),
@@ -98,7 +106,7 @@ def masked_tfidf_pallas(doc_ids, qidf_t, tf, dl_t, keep, *, n_docs: int,
         out_specs=pl.BlockSpec((1, bd), lambda db, ebk: (0, db)),
         out_shape=jax.ShapeDtypeStruct((1, d_tot), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(doc_p, qidf_p, tf_p, dl_p, keep_p)
     return out[0, :n_docs]
 
@@ -127,7 +135,7 @@ def _compact_kernel(pos_ref, keep_ref, val_ref, o_ref, acc_ref, *, block_o):
         # position; invalid rows (keep=0, pos=-1) match no slot
         onehot = ((pos[0][:, None] == out_ids[0][None, :])
                   .astype(jnp.float32) * keep[0][:, None])
-        acc_ref[...] += jnp.dot(val_ref[...], onehot,
+        acc_ref[...] += jnp.dot(val_ref[...], onehot, precision=_EXACT,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(rb == pl.num_programs(1) - 1)
@@ -140,7 +148,7 @@ def _compact_kernel(pos_ref, keep_ref, val_ref, o_ref, acc_ref, *, block_o):
                                     "interpret"))
 def compact_prefix_pallas(vals, pos, keep, *, out_capacity: int,
                           block_r: int = 512, block_o: int = 256,
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """Prefix-sum compaction of ``C`` stacked value rows: ``out[c, j] =
     vals[c, i]`` for the row ``i`` whose exclusive mask prefix-sum is ``j``
     (``pos = cumsum(valid) - 1``, computed outside in XLA; the kernel owns
@@ -168,7 +176,7 @@ def compact_prefix_pallas(vals, pos, keep, *, out_capacity: int,
     val_p = jnp.pad(vals.astype(jnp.float32), ((0, 0), (0, r_pad)))
     o_tot = out_capacity + o_pad
 
-    grid = (o_tot // bo, (r + r_pad) // br)
+    grid = check_grid((o_tot // bo, (r + r_pad) // br), "compact_prefix")
     rspec = pl.BlockSpec((1, br), lambda ob, rbk: (0, rbk))
     out = pl.pallas_call(
         functools.partial(_compact_kernel, block_o=bo),
@@ -177,7 +185,7 @@ def compact_prefix_pallas(vals, pos, keep, *, out_capacity: int,
         out_specs=pl.BlockSpec((c, bo), lambda ob, rbk: (0, ob)),
         out_shape=jax.ShapeDtypeStruct((c, o_tot), jnp.float32),
         scratch_shapes=[pltpu.VMEM((c, bo), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(pos_p, keep_p, val_p)
     return out[:, :out_capacity]
 
@@ -195,14 +203,15 @@ def _join_probe_kernel(lk_ref, rk_ref, rkeep_ref, o_ref):
     jvec = jax.lax.broadcasted_iota(jnp.int32, (nb, 1), 0).astype(jnp.float32)
     m = jnp.concatenate([jvec, jnp.ones((nb, 1), jnp.float32)], axis=1)
     # one matmul: col 0 = matched build index, col 1 = match count (0/1)
-    acc = jnp.dot(eq, m, preferred_element_type=jnp.float32)
+    acc = jnp.dot(eq, m, precision=_EXACT,
+                  preferred_element_type=jnp.float32)
     o_ref[...] = acc.T
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_p", "interpret"))
 def join_probe_pallas(lkeys, rkeys, rvalid, *, block_p: int = 512,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """Hash-join probe against a **unique-key** build side, realized as an
     MXU key-equality contraction: for each probe key, the matching build
     row index and a match flag.  Masked (invalid) build rows never match.
@@ -236,7 +245,7 @@ def join_probe_pallas(lkeys, rkeys, rvalid, *, block_p: int = 512,
         in_specs=[pl.BlockSpec((1, bp), lambda pb: (0, pb)), bspec, bspec],
         out_specs=pl.BlockSpec((2, bp), lambda pb: (0, pb)),
         out_shape=jax.ShapeDtypeStruct((2, p + p_pad), jnp.float32),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(lk_p, rk_p, rkeep)
     idx = out[0, :p].astype(jnp.int32)
     matched = out[1, :p] > 0
@@ -269,7 +278,7 @@ def _masked_segagg_kernel(key_ref, val_ref, mw_ref, o_ref, acc_ref,
         # row 0: mask-weighted sums, row 1: mask counts — one matmul each,
         # sharing the one-hot tile
         stacked = jnp.concatenate([val_ref[...] * mw, mw], axis=0)
-        acc_ref[...] += jnp.dot(stacked, onehot,
+        acc_ref[...] += jnp.dot(stacked, onehot, precision=_EXACT,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(rb == pl.num_programs(1) - 1)
@@ -282,7 +291,7 @@ def _masked_segagg_kernel(key_ref, val_ref, mw_ref, o_ref, acc_ref,
                                     "interpret"))
 def masked_segment_agg_pallas(vals, keys, maskw, *, num_groups: int,
                               block_r: int = 512, block_g: int = 256,
-                              interpret: bool = True):
+                              interpret: Optional[bool] = None):
     """Mask-weighted group-by: ``(sums, counts)`` per group id in one
     kernel pass, skipping row blocks whose mask weights are all zero.
 
@@ -306,7 +315,8 @@ def masked_segment_agg_pallas(vals, keys, maskw, *, num_groups: int,
     mw_p = jnp.pad(maskw.astype(jnp.float32), (0, r_pad))[None, :]
     g_tot = num_groups + g_pad
 
-    grid = (g_tot // bg, (r + r_pad) // br)
+    grid = check_grid((g_tot // bg, (r + r_pad) // br),
+                      "masked_segment_agg")
     rspec = pl.BlockSpec((1, br), lambda gb, rbk: (0, rbk))
     out = pl.pallas_call(
         functools.partial(_masked_segagg_kernel, block_g=bg),
@@ -315,6 +325,6 @@ def masked_segment_agg_pallas(vals, keys, maskw, *, num_groups: int,
         out_specs=pl.BlockSpec((2, bg), lambda gb, rbk: (0, gb)),
         out_shape=jax.ShapeDtypeStruct((2, g_tot), jnp.float32),
         scratch_shapes=[pltpu.VMEM((2, bg), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(key_p, val_p, mw_p)
     return out[0, :num_groups], out[1, :num_groups]

@@ -1,7 +1,9 @@
 """Pure-NumPy references for the store kernels.
 
-Used by the property tests (and nothing else): every JAX/Pallas store
-kernel must agree with the straightforward NumPy computation below.
+Used by the property tests and by ``chip_smoke.py``: every JAX/Pallas store
+kernel must agree with the straightforward NumPy computation below.  The
+references that run at deployment size (tens of millions of rows) are
+vectorized; ``tests/test_stores.py`` holds them to per-row loops.
 """
 from __future__ import annotations
 
@@ -9,18 +11,17 @@ import numpy as np
 
 
 def hash_join_ref(lkeys, rkeys):
-    """Reference equi-join probe against a unique-key build side."""
-    lkeys = np.asarray(lkeys)
-    rkeys = np.asarray(rkeys)
-    lut = {int(k): i for i, k in enumerate(rkeys)}
-    idx = np.zeros(lkeys.shape, np.int64)
-    matched = np.zeros(lkeys.shape, bool)
-    for i, k in enumerate(lkeys):
-        j = lut.get(int(k))
-        if j is not None:
-            idx[i] = j
-            matched[i] = True
-    return idx, matched
+    """Reference equi-join probe against a unique-key build side (a
+    repeated build key resolves to its last row)."""
+    lkeys = np.asarray(lkeys, np.int64)
+    rkeys = np.asarray(rkeys, np.int64)
+    if rkeys.size == 0:
+        return np.zeros(lkeys.shape, np.int64), np.zeros(lkeys.shape, bool)
+    uniq, first_rev = np.unique(rkeys[::-1], return_index=True)
+    last = rkeys.size - 1 - first_rev
+    pos = np.minimum(np.searchsorted(uniq, lkeys), uniq.size - 1)
+    matched = uniq[pos] == lkeys
+    return np.where(matched, last[pos], 0).astype(np.int64), matched
 
 
 def join_probe_ref(lkeys, rkeys, rvalid):
@@ -94,30 +95,24 @@ def group_agg_ref(values, keys, num_groups, mask, fn):
     *invalid* (value slot 0.0), never conflated with a true max of 0.0;
     mirrors :func:`repro.stores.column_store.group_agg`.
     """
+    if fn not in ("count", "sum", "mean", "max"):
+        raise ValueError(fn)
     keys = np.asarray(keys)
-    mask = np.asarray(mask, bool)
-    out = np.zeros(num_groups, np.float64)
-    valid = np.zeros(num_groups, bool)
-    for g in range(num_groups):
-        sel = (keys == g) & mask
-        if fn == "count":
-            out[g] = sel.sum()
-            continue
-        v = np.asarray(values, np.float64)[sel]
-        if v.size == 0:
-            out[g] = 0.0
-        elif fn == "sum":
-            out[g] = v.sum()
-        elif fn == "mean":
-            out[g] = v.mean()
-        elif fn == "max":
-            out[g] = v.max()
-            valid[g] = np.isfinite(out[g])
-        else:
-            raise ValueError(fn)
+    sel = np.asarray(mask, bool) & (keys >= 0) & (keys < num_groups)
+    k = keys[sel].astype(np.int64)
+    counts = np.bincount(k, minlength=num_groups).astype(np.float64)
+    if fn == "count":
+        return counts.astype(np.float32)
+    v = np.asarray(values, np.float64)[sel]
     if fn == "max":
+        out = np.full(num_groups, -np.inf)
+        np.maximum.at(out, k, v)
+        valid = (counts > 0) & np.isfinite(out)
         return np.where(valid, out, 0.0).astype(np.float32), valid
-    return out.astype(np.float32)
+    sums = np.bincount(k, weights=v, minlength=num_groups)
+    if fn == "mean":
+        sums = np.where(counts > 0, sums / np.maximum(counts, 1.0), 0.0)
+    return sums.astype(np.float32)
 
 
 def spmv_ref(src, dst, weights, n_nodes, x):
@@ -158,11 +153,13 @@ def triangle_count_ref(src, dst, n_nodes):
 
 
 def tfidf_scores_ref(doc_ids, term_ids, tf, doc_len, idf, query):
-    scores = np.zeros(len(doc_len), np.float64)
-    q = np.asarray(query, np.float64)
-    for d, t, f in zip(doc_ids, term_ids, np.asarray(tf, np.float64)):
-        scores[d] += q[t] * idf[t] * f / doc_len[d]
-    return scores
+    d = np.asarray(doc_ids)
+    t = np.asarray(term_ids)
+    contrib = (np.asarray(query, np.float64)[t]
+               * np.asarray(idf, np.float64)[t]
+               * np.asarray(tf, np.float64)
+               / np.asarray(doc_len, np.float64)[d])
+    return np.bincount(d, weights=contrib, minlength=len(doc_len))
 
 
 def masked_tfidf_scores_ref(doc_ids, term_ids, tf, doc_len, idf, query,

@@ -128,7 +128,7 @@ def _step_rel_join(left, right, attrs, ctx=None):
     return BoundedRel(cols, valid, None, left.overflow | right.overflow)
 
 
-def _step_rel_join_probe(left, right, attrs, ctx=None, interpret=True):
+def _step_rel_join_probe(left, right, attrs, ctx=None):
     """The Pallas probe realization of ``rel_join``: key equality on the
     MXU against the (expected-count-bounded) build side.  Invalid build
     rows never match, so validity needs no second gather; gathered values
@@ -137,7 +137,7 @@ def _step_rel_join_probe(left, right, attrs, ctx=None, interpret=True):
     left, right = as_bounded(left), as_bounded(right)
     lo, ro = attrs["left_on"], attrs["right_on"]
     idx, matched = join_probe_pallas(left.cols[lo], right.cols[ro],
-                                     right.valid, interpret=interpret)
+                                     right.valid)
     cols = _merge_join_cols(left, right, ro, idx)
     valid = left.valid & matched
     return BoundedRel(cols, valid, None, left.overflow | right.overflow)
@@ -191,7 +191,7 @@ def _record_overflow(ctx, attrs, out):
                       out.overflow, 1)
 
 
-def _step_compact_pallas(tbl, attrs, ctx=None, interpret=True):
+def _step_compact_pallas(tbl, attrs, ctx=None):
     """Pallas realization of ``compact``: destination positions from an
     XLA prefix sum, the scatter as the one-hot-matmul compaction kernel.
     Bit-exact for float columns; integer columns round-trip through
@@ -204,8 +204,7 @@ def _step_compact_pallas(tbl, attrs, ctx=None, interpret=True):
                     -1)
     names = tuple(rel.cols)
     stacked = jnp.stack([rel.cols[n].astype(jnp.float32) for n in names])
-    out = compact_prefix_pallas(stacked, pos, keep, out_capacity=cap,
-                                interpret=interpret)
+    out = compact_prefix_pallas(stacked, pos, keep, out_capacity=cap)
     count = jnp.minimum(rel.count, cap).astype(jnp.int32)
     valid = jnp.arange(cap, dtype=jnp.int32) < count
     cols = {}
@@ -280,8 +279,7 @@ def _i_rel_join(ctx, args, node):
 
 @_PALLAS.impl("rel_join_probe_pallas")
 def _i_rel_join_probe(ctx, args, node):
-    return _step_rel_join_probe(args[0], args[1], node.attrs, ctx,
-                                interpret=ctx.interpret)
+    return _step_rel_join_probe(args[0], args[1], node.attrs, ctx)
 
 
 @REL_ENGINE.impl("bounded_join_col")
@@ -354,8 +352,7 @@ def _i_compact(ctx, args, node):
 
 @_PALLAS.impl("compact_prefix_pallas")
 def _i_compact_pallas(ctx, args, node):
-    return _step_compact_pallas(args[0], node.attrs, ctx,
-                                interpret=ctx.interpret)
+    return _step_compact_pallas(args[0], node.attrs, ctx)
 
 
 @REL_ENGINE.impl("rel_fused_col")
@@ -377,15 +374,13 @@ def _i_rel_fused_agg(ctx, args, node):
     count = None
     for out_name, fn, col in attrs["aggs"]:
         vals = mw if fn == "count" else rel.cols[col]
-        s, c = masked_segment_agg_pallas(vals, key, mw, num_groups=g,
-                                         interpret=ctx.interpret)
+        s, c = masked_segment_agg_pallas(vals, key, mw, num_groups=g)
         count = c
         cols[out_name] = (c if fn == "count"
                           else s if fn == "sum"
                           else s / jnp.maximum(c, 1.0))
     if count is None:
-        count, _ = masked_segment_agg_pallas(mw, key, mw, num_groups=g,
-                                             interpret=ctx.interpret)
+        count, _ = masked_segment_agg_pallas(mw, key, mw, num_groups=g)
     return BoundedRel(cols, count > 0, None, rel.overflow)
 
 
@@ -444,7 +439,7 @@ def _i_expand_skip(ctx, args, node):
 def _i_expand_pallas(ctx, args, node):
     return expand_frontier(args[0], args[1],
                            hops=int(node.attrs.get("hops", 1)),
-                           use_pallas=True, interpret=ctx.interpret)
+                           use_pallas=True)
 
 
 @GRAPH_ENGINE.impl("graph_pagerank_csr")
@@ -481,7 +476,7 @@ def _i_pagerank_pallas(ctx, args, node):
     return pagerank(args[0], iters=int(node.attrs.get("iters", 10)),
                     damping=float(node.attrs.get("damping", 0.85)),
                     personalization=args[1] if len(args) > 1 else None,
-                    use_pallas=True, interpret=ctx.interpret)
+                    use_pallas=True)
 
 
 @GRAPH_ENGINE.impl("graph_tricount_csr")
@@ -538,7 +533,7 @@ def _i_text_topk_pallas(ctx, args, node):
     scores = masked_tfidf_pallas(
         doc_ids, w[corpus["term_ids"]], corpus["tf"],
         corpus["doc_len"][doc_ids], doc_mask[doc_ids],
-        n_docs=int(corpus["doc_len"].shape[0]), interpret=ctx.interpret)
+        n_docs=int(corpus["doc_len"].shape[0]))
     return _topk_rel(*masked_topk(scores, doc_mask, int(node.attrs["k"])))
 
 
@@ -587,14 +582,8 @@ def _i_xfer_replicate(ctx, args, node):
     if mesh is None:
         return args[0]
     rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-
-    def pin(a):
-        try:
-            return jax.lax.with_sharding_constraint(a, rep)
-        except Exception:
-            return a
-
-    return jax.tree.map(pin, args[0])
+    return jax.tree.map(lambda a: jax.lax.with_sharding_constraint(a, rep),
+                        args[0])
 
 
 @_XLA.impl("xfer_spill")

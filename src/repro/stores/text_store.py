@@ -83,6 +83,16 @@ class TextStore:
         return cls(doc_ids, term_ids, tfs, doc_len, cls._idf(len(docs), df),
                    vocab, shards=shards)
 
+    @classmethod
+    def from_postings(cls, doc_ids, term_ids, tf, doc_len,
+                      vocab: int) -> "TextStore":
+        """An index given directly as COO postings (one per distinct
+        ``(doc, term)``, ``tf`` its count) plus per-doc token counts — the
+        vectorized ingest for corpora too large to list doc by doc."""
+        df = np.bincount(np.asarray(term_ids, np.int64), minlength=vocab)
+        return cls(doc_ids, term_ids, tf, doc_len,
+                   cls._idf(len(doc_len), df), vocab)
+
     def with_shards(self, shards: int) -> "TextStore":
         """This corpus re-declared as document-partitioned over ``shards``
         mesh slices (pads the doc domain to a shard multiple)."""

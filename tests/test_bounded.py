@@ -179,7 +179,7 @@ def test_compact_pallas_matches_gather(rng):
     rel = cs.payload().narrowed(jnp.asarray(rng.rand(90) > 0.7))
     for cap in (8, 40):
         xla = _step_compact(rel, {"capacity": cap})
-        pls = _step_compact_pallas(rel, {"capacity": cap}, interpret=True)
+        pls = _step_compact_pallas(rel, {"capacity": cap})
         assert int(xla.count) == int(pls.count)
         v = np.asarray(xla.valid)
         np.testing.assert_array_equal(v, np.asarray(pls.valid))

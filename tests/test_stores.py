@@ -83,6 +83,79 @@ def test_hash_join_matches_reference(rng):
     np.testing.assert_array_equal(np.asarray(idx)[rmatched], ridx[rmatched])
 
 
+def _group_agg_loop(values, keys, num_groups, mask, fn):
+    out = np.zeros(num_groups, np.float64)
+    valid = np.zeros(num_groups, bool)
+    for g in range(num_groups):
+        sel = (keys == g) & mask
+        if fn == "count":
+            out[g] = sel.sum()
+            continue
+        v = np.asarray(values, np.float64)[sel]
+        if v.size == 0:
+            continue
+        out[g] = {"sum": v.sum, "mean": v.mean, "max": v.max}[fn]()
+        valid[g] = fn == "max" and np.isfinite(out[g])
+    if fn == "max":
+        return np.where(valid, out, 0.0).astype(np.float32), valid
+    return out.astype(np.float32)
+
+
+def _tfidf_loop(doc_ids, term_ids, tf, doc_len, idf, query):
+    scores = np.zeros(len(doc_len), np.float64)
+    q = np.asarray(query, np.float64)
+    for d, t, f in zip(doc_ids, term_ids, np.asarray(tf, np.float64)):
+        scores[d] += q[t] * idf[t] * f / doc_len[d]
+    return scores
+
+
+def _hash_join_loop(lkeys, rkeys):
+    lut = {int(k): i for i, k in enumerate(rkeys)}
+    idx = np.zeros(lkeys.shape, np.int64)
+    matched = np.zeros(lkeys.shape, bool)
+    for i, k in enumerate(lkeys):
+        j = lut.get(int(k))
+        if j is not None:
+            idx[i], matched[i] = j, True
+    return idx, matched
+
+
+@pytest.mark.parametrize("case", ["count", "sum", "mean", "max", "tfidf",
+                                  "join", "join_repeated_keys"])
+def test_vectorized_refs_match_per_row_loops(rng, case):
+    """The references run at deployment size vectorized; the per-row loops
+    they replaced stay here as their reference.  Counts, joins and TF-IDF
+    (same float64 products, same summation order) match exactly; sums and
+    means re-associate in float64, so float32 outputs agree to 1e-6."""
+    if case == "tfidf":
+        tx = TextStore.from_docs(
+            [rng.randint(0, 40, rng.randint(1, 9)) for _ in range(60)], 40)
+        q = rng.rand(40)
+        got = R.tfidf_scores_ref(tx.doc_ids, tx.term_ids, tx.tf,
+                                 tx.doc_len, tx.idf, q)
+        want = _tfidf_loop(tx.doc_ids, tx.term_ids, tx.tf, tx.doc_len,
+                           tx.idf, q)
+        np.testing.assert_array_equal(got, want)
+    elif case.startswith("join"):
+        rkeys = (rng.randint(0, 30, 40) if case == "join_repeated_keys"
+                 else rng.permutation(50)[:32])
+        lkeys = rng.randint(-5, 55, 80)
+        for got, want in zip(R.hash_join_ref(lkeys, rkeys),
+                             _hash_join_loop(lkeys, rkeys)):
+            np.testing.assert_array_equal(got, want)
+    else:
+        keys = rng.randint(-1, 12, 200)
+        vals = rng.randn(200).astype(np.float32)
+        mask = rng.rand(200) > 0.3
+        got = R.group_agg_ref(None if case == "count" else vals, keys, 10,
+                              mask, case)
+        want = _group_agg_loop(vals, keys, 10, mask, case)
+        if case == "max":
+            (got, gvalid), (want, wvalid) = got, want
+            np.testing.assert_array_equal(gvalid, wvalid)
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=0)
+
+
 def test_group_agg_matches_reference(rng):
     keys = rng.randint(0, 8, 100).astype(np.int32)
     vals = rng.randn(100).astype(np.float32)
@@ -325,6 +398,33 @@ def test_pallas_graph_candidates_selected_and_close(rng):
                                atol=1e-6)
 
 
+def test_onehot_kernels_priced_by_grid_work():
+    """The one-hot Pallas kernels visit out-blocks x in-blocks: at the
+    deployment size (2^23 tweets, 2^18 hashtags, 2^24 edges, 59M postings)
+    that grid loses to the CSR / columnar paths, which the small graph of
+    the test above does not; a grid past int32 steps cannot compile."""
+    from types import SimpleNamespace as NS
+
+    from repro.core.cost_model import op_cost
+    tweets, tags = 1 << 23, 1 << 18
+    table = NS(type=TableT((("user", "int32"), ("hashtag", "int32"),
+                            ("doc", "int32"), ("engagement", "float32")),
+                           tweets))
+    graph = NS(type=GraphT(tags, 1 << 24), n_nodes=tags)
+    corpus = NS(type=CorpusT(tweets, 1 << 16, 59_000_000), vocab=1 << 16)
+    fn = _tri_analysis(table, graph, corpus).compile(
+        SYS, engines=store_engines(pallas=True), cache=False)
+    chosen = {r["pattern"]: r["chosen"] for r in fn.report}
+    assert chosen["graph_expand_op"] == "expand_csr"
+    assert chosen["graph_pagerank_op"] == "pagerank_csr"
+    # the 2^23-row hot-tweet group-by stays columnar
+    assert "rel_fused_col" in [r["chosen"] for r in fn.report
+                               if r["pattern"] == "rel_fused_op"]
+    c = op_cost("text_topk_masked_pallas", [corpus.type],
+                {"k": 64, "selectivity": 0.1}, SYS)
+    assert c.flops == float("inf")
+
+
 # --------------------------------------------------------------------------
 # ADIL front ends: native table/graph/corpus declarations
 # --------------------------------------------------------------------------
@@ -372,6 +472,44 @@ def test_parser_store_declarations_match_builder():
         b.store(pr)
         b.store(hits)
     assert plan_id(parsed.plan, CAT, SYS) == plan_id(b.plan, CAT, SYS)
+
+
+def _tri_src_stores(rng):
+    """Stores matching TRI_SRC's declarations exactly."""
+    table = ColumnStore({"hashtag": rng.randint(0, 16, 100).astype(np.int32),
+                         "engagement": rng.rand(100).astype(np.float32)})
+    graph = GraphStore.from_edges(rng.randint(0, 16, 64),
+                                  rng.randint(0, 16, 64), 16)
+    docs = np.repeat(np.arange(100), 4)
+    terms = np.tile(np.arange(4), 100) + 4 * (docs % 8)
+    corpus = TextStore.from_postings(docs, terms, np.ones(400), np.full(100, 4),
+                                     32)
+    return table, graph, corpus
+
+
+def test_bind_attaches_stores_to_declared_inputs(rng):
+    table, graph, corpus = _tri_src_stores(rng)
+    parsed = parse_adil(TRI_SRC, CAT)
+    for name, store in (("tweets", table), ("g", graph), ("cx", corpus)):
+        parsed.bind(name, store)
+    assert parsed.store_versions() == (("cx", 0), ("g", 0), ("tweets", 0))
+    fn = parsed.compile(SYS, engines=store_engines(), cache=False)
+    inputs = {"tweets": table.payload(), "g": graph.payload(),
+              "cx": corpus.payload(),
+              "q": jnp.asarray(corpus.query_vector([1, 2]))}
+    pr, hits = fn({}, inputs)
+    assert pr.shape == (16,) and int(hits.count) == 5
+
+    # a sharded store keeps the declaration and brings its own placement
+    sharded = parse_adil(TRI_SRC, CAT)
+    sharded.bind("tweets", table.with_shards(2))
+    sharded.compile(SYS, engines=store_engines(), cache=False)
+    assert sharded.plan.inputs["tweets"].partitioning == "row"
+
+    short = ColumnStore({"hashtag": np.zeros(99, np.int32),
+                         "engagement": np.zeros(99, np.float32)})
+    with pytest.raises(ValidationError, match="does not match"):
+        parse_adil(TRI_SRC, CAT).bind("tweets", short)
 
 
 def test_tri_store_type_validation():
