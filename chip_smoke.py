@@ -250,9 +250,9 @@ def analysis_phase(rt, seed, sizes):
     batched = sum(r.batched for r in results)
     log(f"single-flight twins {deduped}, vmapped {batched}; "
         f"{rt.metrics.analytics_report()}")
+    check_no_hidden_failures(rt.recorder)
     check(deduped > 0, "no exact twin ran single-flight")
     check(batched > 0, "the batch_param group did not run vmapped")
-    check_no_hidden_failures(rt.recorder)
 
     t0 = time.perf_counter()
     pr = graph_reference(table, graph)
@@ -273,10 +273,14 @@ def analysis_phase(rt, seed, sizes):
 
 
 def check_no_hidden_failures(recorder):
-    kinds = [ev.kind for ev in recorder.events()]
-    kinds += [reason for reason, _ in recorder.trips]
     check(recorder.dropped == 0, "flight recorder dropped events")
-    hidden = sorted({k for k in kinds if k in HIDDEN_FAILURES})
+    hidden = []
+    for ev in recorder.events():
+        kind, info = ev.kind, ev.payload
+        if kind == "trip":   # executor_error / breaker_open dumps
+            kind, info = info.get("reason"), info.get("detail", {})
+        if kind in HIDDEN_FAILURES:
+            hidden.append((kind, info.get("error")))
     check(not hidden, f"flight recorder holds {hidden}")
 
 

@@ -242,6 +242,23 @@ def test_tfidf_matches_reference(rng):
     assert bool(np.asarray(valid).all())
 
 
+@pytest.mark.parametrize("batched_corpus", [False, True])
+def test_tfidf_batched_matches_per_query(rng, batched_corpus):
+    docs = [rng.randint(0, 16, rng.randint(2, 8)) for _ in range(20)]
+    tx = TextStore.from_docs(docs, 16)
+    qs = jnp.asarray(np.stack([tx.query_vector(rng.randint(0, 16, 3))
+                               for _ in range(3)]))
+    corpus = tx.payload()
+    if batched_corpus:
+        stacked = jax.tree.map(lambda x: jnp.stack([x] * 3), corpus)
+        got = jax.vmap(tfidf_scores)(stacked, qs)
+    else:
+        got = jax.vmap(tfidf_scores, in_axes=(None, 0))(corpus, qs)
+    for i in range(3):
+        want = tfidf_scores(corpus, qs[i])
+        np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want))
+
+
 # --------------------------------------------------------------------------
 # xfer placement
 # --------------------------------------------------------------------------

@@ -22,12 +22,14 @@ from repro.stores.masked_kernels import (compact_prefix_pallas,
                                          join_probe_pallas,
                                          masked_segment_agg_pallas,
                                          masked_tfidf_pallas)
+from repro.stores.text_store import tfidf_scores
 
 # the deployment widths of chip_smoke.py's tri-model analysis
 TWEETS = 1 << 23
 HASHTAGS = 1 << 18
 EDGES = 1 << 24
 POSTINGS = 59_000_000
+VOCAB = 1 << 16
 
 
 @pytest.fixture(scope="module")
@@ -167,3 +169,23 @@ def test_join_probe_compiles_at_the_planner_gate(one_chip):
                       _spec(one_chip, (1 << 16,), jnp.int32),
                       _spec(one_chip, (JOIN_PROBE_MAX_BUILD,), jnp.int32),
                       _spec(one_chip, (JOIN_PROBE_MAX_BUILD,), jnp.bool_))
+
+
+def test_batched_tfidf_stays_query_major(one_chip):
+    # four queries of a batch_param group over an eighth of the smoke's
+    # corpus: the batched scorer must stay near one query's footprint (a
+    # batch carried as the minor dim of the postings pads 4 lanes to 128
+    # on a TPU: ~30 GB at the full corpus)
+    postings, docs = POSTINGS // 8, TWEETS // 8
+    corpus = {"doc_ids": _spec(one_chip, (postings,), jnp.int32),
+              "term_ids": _spec(one_chip, (postings,), jnp.int32),
+              "tf": _spec(one_chip, (postings,), jnp.float32),
+              "doc_len": _spec(one_chip, (docs,), jnp.float32),
+              "idf": _spec(one_chip, (VOCAB,), jnp.float32)}
+    one = jax.jit(tfidf_scores).lower(
+        corpus, _spec(one_chip, (VOCAB,), jnp.float32)).compile()
+    four = jax.jit(jax.vmap(tfidf_scores, in_axes=(None, 0))).lower(
+        corpus, _spec(one_chip, (4, VOCAB), jnp.float32)).compile()
+    temp1 = one.memory_analysis().temp_size_in_bytes
+    temp4 = four.memory_analysis().temp_size_in_bytes
+    assert temp4 <= 2 * temp1 + 4 * 4 * docs, (temp1, temp4)
