@@ -61,8 +61,9 @@ ANALYSIS_SIZES = dict(tweets=1 << 23, hashtags=1 << 18, users=1 << 20,
                       vocab=1 << 16, doc_tokens=(7, 15))
 # the ADIL program's constants (examples/tri_model_analysis.py)
 HOT_ENGAGEMENT, HOPS, PR_ITERS, DAMPING, TOP_K = 30.0, 2, 8, 0.85, 64
-# float32 stores against float64 NumPy: sums over up to ~60 in-edges per
-# node through 10 SpMV passes, and over <= 64 text scores per hashtag
+# float32 stores against float64 NumPy: the hub hashtag sums ~3M in-edges
+# (over 128 accumulator lanes) in each of 10 SpMV passes, and each hashtag
+# sums <= 64 text scores
 SCORE_RTOL, SCORE_ATOL_REL = 1e-4, 1e-6
 # launch/serve.py's defaults
 LM_ARCH, LM_PROMPT_LENS, LM_GEN = "qwen3-0.6b", (5, 12, 8, 20, 16, 3, 27, 9), 16
@@ -420,8 +421,11 @@ def four_chip_phase(seed, devices, sizes):
     log(f"store bytes split over 4 devices: {split_bytes} of {total}")
 
     four = np.asarray(fn4({}, inputs))
-    err = score_error(four, one.astype(np.float64))
-    log(f"four chips vs one chip: worst error {err:.3g} of tolerance")
+    # documents tied at the k-th TF-IDF score may be kept in another order
+    _, kth, spare = text_reference(table, corpus, graph.n_nodes, queries[0])
+    err, swapped = analysis_error(four, one.astype(np.float64), kth, spare)
+    log(f"four chips vs one chip: worst error {err:.3g} of tolerance; tied "
+        f"k-th documents exchanged: {swapped}")
     check(err <= 1.0, f"sharded result off the one-chip result: {err:.3g}")
     in_use = [(d.memory_stats() or {}).get("bytes_in_use", 0)
               for d in devices]

@@ -780,9 +780,10 @@ def predicted_resident_bytes(t) -> Optional[int]:
         return None
     extra = 0
     if isinstance(t, GraphT) and getattr(t, "partitioning", None):
-        # dst-block payload: blk_src + blk_dst_local (int32) + blk_weights
-        # (f32), each padded to the max per-block edge count ~ edges total
-        extra = t.edges * 12
+        # dst-block payload: blk_src + blk_dst_local + blk_pos (int32) +
+        # blk_weights (f32), each padded to the max per-block edge count ~
+        # edges total
+        extra = t.edges * 16
     elif isinstance(t, CorpusT) and getattr(t, "partitioning", None):
         # doc-block payload: blk_doc_local + blk_term_ids (int32) + blk_tf
         # (f32) padded per partition ~ postings total

@@ -36,6 +36,7 @@ from jax.experimental.shard_map import shard_map
 
 from ..core.ir import ValidationError
 from .column_store import hash_join, hash_join_nonunique
+from .graph_store import accumulator_lanes, lane_segment_sum
 
 P = jax.sharding.PartitionSpec
 
@@ -233,15 +234,23 @@ def sharded_partitioned_join(lkeys, lmask, rkeys, rmask, capacity: int,
 # --------------------------------------------------------------------------
 
 
-def _block_spmv(xs_local, blk_src, blk_dstl, blk_w, n_local: int):
+def _block_spmv(xs_local, blk_src, blk_dstl, blk_w, blk_pos, n_local: int,
+                lanes: int):
     """One SpMV step over this shard's dst-block edges.  ``xs_local`` is
     the shard's slice of the source vector; the full vector is gathered
     (the per-iteration frontier all-gather), contributions are computed in
-    the stable dst-block edge order, and pad edges (``dst_local ==
+    the stable dst-block edge order into the dense SpMV's accumulator
+    lanes (by CSR position ``blk_pos``), and pad edges (``dst_local ==
     n_local``) are dropped by the scatter."""
     xs = jax.lax.all_gather(xs_local, "data", tiled=True)
-    return jax.ops.segment_sum(xs[blk_src] * blk_w, blk_dstl,
-                               num_segments=n_local)
+    return lane_segment_sum(xs[blk_src] * blk_w, blk_dstl, n_local, lanes,
+                            pos=blk_pos)
+
+
+def _graph_lanes(g: dict) -> int:
+    """The dense SpMV's accumulator lanes (from the global graph shape)."""
+    return accumulator_lanes(int(g["src"].shape[0]),
+                             int(g["indptr"].shape[0]) - 1)
 
 
 def sharded_pagerank(g: dict, iters: int, damping: float,
@@ -258,7 +267,9 @@ def sharded_pagerank(g: dict, iters: int, damping: float,
     p = (personalization.astype(jnp.float32) if has_p
          else jnp.full((n,), 1.0 / n, jnp.float32))
 
-    def body(p_l, deg_l, src_b, dst_b, w_b):
+    lanes = _graph_lanes(g)
+
+    def body(p_l, deg_l, src_b, dst_b, w_b, pos_b):
         if has_p:
             p_full = jax.lax.all_gather(p_l, "data", tiled=True)
             p0_l = p_l / jnp.maximum(jnp.sum(p_full), 1e-30)
@@ -266,13 +277,15 @@ def sharded_pagerank(g: dict, iters: int, damping: float,
             p0_l = p_l
         r_l = p0_l
         for _ in range(int(iters)):
-            y_l = _block_spmv(r_l / deg_l, src_b, dst_b, w_b, n_local)
+            y_l = _block_spmv(r_l / deg_l, src_b, dst_b, w_b, pos_b,
+                              n_local, lanes)
             r_l = (1.0 - damping) * p0_l + damping * y_l
         return r_l
 
-    return shard_map(body, mesh=mesh, in_specs=(P("data"),) * 5,
+    return shard_map(body, mesh=mesh, in_specs=(P("data"),) * 6,
                      out_specs=P("data"))(
-        p, g["out_deg"], g["blk_src"], g["blk_dst_local"], g["blk_weights"])
+        p, g["out_deg"], g["blk_src"], g["blk_dst_local"], g["blk_weights"],
+        g["blk_pos"])
 
 
 def sharded_expand(g: dict, frontier, hops: int, mesh):
@@ -281,15 +294,18 @@ def sharded_expand(g: dict, frontier, hops: int, mesh):
     n = int(g["indptr"].shape[0]) - 1
     n_local = n // data_axis_size(mesh)
 
-    def body(x_l, src_b, dst_b, w_b):
+    lanes = _graph_lanes(g)
+
+    def body(x_l, src_b, dst_b, w_b, pos_b):
         x_l = x_l.astype(jnp.float32)
         for _ in range(int(hops)):
-            x_l = _block_spmv(x_l, src_b, dst_b, w_b, n_local)
+            x_l = _block_spmv(x_l, src_b, dst_b, w_b, pos_b, n_local, lanes)
         return x_l
 
-    return shard_map(body, mesh=mesh, in_specs=(P("data"),) * 4,
+    return shard_map(body, mesh=mesh, in_specs=(P("data"),) * 5,
                      out_specs=P("data"))(
-        frontier, g["blk_src"], g["blk_dst_local"], g["blk_weights"])
+        frontier, g["blk_src"], g["blk_dst_local"], g["blk_weights"],
+        g["blk_pos"])
 
 
 # --------------------------------------------------------------------------

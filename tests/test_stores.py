@@ -16,7 +16,10 @@ from repro.stores import (ColumnStore, GraphStore, TextStore, store_engines)
 from repro.stores import ref as R
 from repro.stores.column_store import filter_mask, group_agg, hash_join
 from repro.stores.graph_kernels import scatter_add_pallas
-from repro.stores.graph_store import expand_frontier, pagerank, triangle_count
+from repro.stores.graph_store import (accumulator_lanes, expand_frontier,
+                                      expand_frontier_blockskip,
+                                      lane_segment_sum, pagerank,
+                                      triangle_count)
 from repro.stores.text_store import tfidf_scores, tfidf_topk
 
 CAT = standard_catalog()
@@ -199,6 +202,61 @@ def test_graph_ops_match_reference(rng):
 
     got_t = float(triangle_count(gp))
     assert got_t == pytest.approx(R.triangle_count_ref(g.src, g.indices, n))
+
+
+@pytest.mark.parametrize("lanes", [1, 8])
+def test_lane_segment_sum_matches_float64(rng, lanes):
+    n, e = 50, 4000
+    seg = rng.randint(-1, n + 1, e).astype(np.int32)   # ids -1, n dropped
+    vals = rng.randn(e).astype(np.float32)
+    got = lane_segment_sum(jnp.asarray(vals), jnp.asarray(seg), n, lanes)
+    keep = (seg >= 0) & (seg < n)
+    want = np.bincount(seg[keep], vals[keep].astype(np.float64), n)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-4)
+    if lanes == 1:
+        plain = jax.ops.segment_sum(jnp.asarray(vals), jnp.asarray(seg),
+                                    num_segments=n)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
+
+
+def _hub_graph(rng):
+    # half of 2^17 random edges land on node 0: a hub, as a Zipf-popular
+    # hashtag is in the co-mention graph
+    n, e = 2048, 1 << 17
+    src = rng.randint(1, n, e)
+    dst = np.where(rng.rand(e) < 0.5, 0, rng.randint(1, n, e))
+    return GraphStore.from_edges(src, dst, n, symmetric=True)
+
+
+def test_hub_sums_stay_near_float64(rng):
+    """A hub's in-edges spread over accumulator lanes: one float32
+    accumulator loses 3.3e-5 of this expansion, lanes keep it at ~2e-6."""
+    g = _hub_graph(rng)
+    n = g.n_nodes
+    assert accumulator_lanes(g.n_edges, n) == 8
+    assert accumulator_lanes(1 << 15, n) == 1
+    x = (rng.rand(n) ** 4).astype(np.float32)
+    gp = g.payload()
+    fr = R.expand_ref(g.src, g.indices, g.weights, n, x, hops=2)
+    got = np.asarray(expand_frontier(gp, jnp.asarray(x), hops=2))
+    assert np.abs(got - fr).max() / fr.max() < 5e-6
+    pr = R.pagerank_ref(g.src, g.indices, g.weights, n, iters=8,
+                        personalization=fr)
+    got_pr = np.asarray(pagerank(gp, iters=8, personalization=jnp.asarray(got)))
+    assert np.abs(got_pr - pr).max() / pr.max() < 5e-6
+
+
+def test_blockskip_keeps_lanes_bitwise(rng):
+    g = _hub_graph(rng)
+    gp = g.payload()
+    fr = np.where(rng.rand(g.n_nodes) < 0.05, rng.rand(g.n_nodes), 0.0)
+    fr = jnp.asarray(fr.astype(np.float32))
+    got = expand_frontier_blockskip(gp, fr, hops=2, block=1000)
+    want = expand_frontier(gp, fr, hops=2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = pagerank(gp, iters=3, personalization=fr, skip_first=True)
+    want = pagerank(gp, iters=3, personalization=fr)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_scatter_add_pallas_matches_segment_sum(rng):
