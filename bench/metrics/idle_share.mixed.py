@@ -1,0 +1,2 @@
+"""idle_share.mixed: percent of the traced window the device was idle."""
+from bench.readers import idle_share as read  # noqa: F401
