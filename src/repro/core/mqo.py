@@ -45,6 +45,7 @@ from typing import Any, Mapping, Optional, Sequence
 import numpy as np
 
 from .executor import ExecContext, run_plan_subset
+from . import tracing
 from .ir import subdag_fingerprints
 from .tracing import tree_bytes
 
@@ -428,20 +429,27 @@ def mqo_run(planned, params, inputs: Mapping[str, Any], *,
     if keys is None:
         keys = subdag_keys(planned, inputs, versions=versions,
                            params=params, input_keys=input_keys)
-    hits, residual = split_at_frontier(pplan, keys, cache)
+    with tracing.span("mqo.frontier") as sp:
+        hits, residual = split_at_frontier(pplan, keys, cache)
+        sp.set_metadata(hits=len(hits), residual=len(residual))
     ctx = ExecContext(root=params, scope=params, aux=aux or {},
                       mesh=planned.mesh, rules=planned.rules)
     env = dict(inputs)
     env.update(hits)
-    env = run_plan_subset(pplan, ctx, env, residual)
+    with tracing.span("mqo.dispatch", nodes=len(residual)):
+        env = run_plan_subset(pplan, ctx, env, residual)
     vers = tuple(dict(versions).items())
-    for nid in residual:
-        n = pplan.nodes[nid]
-        if n.impl in _SKIP_CACHE_IMPLS or n.virtual:
-            continue
-        key = keys.get(nid)
-        if key is not None:
-            cache.insert(key, env[nid], stores=vers, tied_to=tied_to)
+    with tracing.span("mqo.insert") as sp:
+        inserted = 0
+        for nid in residual:
+            n = pplan.nodes[nid]
+            if n.impl in _SKIP_CACHE_IMPLS or n.virtual:
+                continue
+            key = keys.get(nid)
+            if key is not None and cache.insert(key, env[nid], stores=vers,
+                                                tied_to=tied_to):
+                inserted += tree_bytes(env[nid])
+        sp.set_metadata(bytes=inserted)
     info = {"plan_id": getattr(planned, "plan_id", ""),
             "shared_hits": len(hits), "executed": len(residual),
             "total": len(pplan.nodes)}

@@ -1,4 +1,5 @@
-"""Runtime tracing + metrics: the EXPLAIN ANALYZE substrate.
+"""Runtime tracing + metrics: the EXPLAIN ANALYZE substrate, and the
+program's spans on the profiler's clock.
 
 The planner's cost model (paper §6) predicts; nothing so far *checked* the
 prediction.  This module supplies the runtime half of that loop:
@@ -22,6 +23,15 @@ prediction.  This module supplies the runtime half of that loop:
     model from), and exporters — structured JSON-lines
     (:meth:`RunTrace.to_jsonl`) and Chrome-trace / Perfetto-loadable JSON
     (:meth:`RunTrace.to_chrome`).
+
+  * :func:`span` — the program's one span entry point: a
+    ``jax.profiler.TraceAnnotation`` named ``repro.<layer>.<what>`` whose
+    attributes land as event stats in the profile, on the same clock as
+    the device's ops.  The served path (``serving/runtime.py``,
+    ``core/mqo.py``) opens one at each layer boundary, and every
+    :meth:`Tracer.span` enters one too, so EXPLAIN ANALYZE spans show in a
+    profile taken meanwhile.  With no profiler running an annotation
+    records nothing: the profiler being on is the only switch.
 
 Span wall times are *dispatch* times under JAX's async dispatch; the
 single ``device_sync`` span at the end of an analyzed run absorbs whatever
@@ -48,6 +58,15 @@ import jax
 # --------------------------------------------------------------------------
 # spans
 # --------------------------------------------------------------------------
+
+
+def span(name: str, **attrs):
+    """A profiler annotation ``repro.<name>`` (``name`` is
+    ``<layer>.<what>``) carrying ``attrs`` as event stats.  Use it as a
+    context manager; ``set_metadata(**attrs)`` on the entered annotation
+    adds attributes known only once the work is done.  Costs about a
+    microsecond and records nothing while no profiler runs."""
+    return jax.profiler.TraceAnnotation("repro." + name, **attrs)
 
 
 @dataclass
@@ -115,7 +134,10 @@ class Tracer:
                   stack[-1].span_id if stack else None, attrs)
         stack.append(sp)
         try:
-            yield sp
+            # the module-level entry point: the span shows in a profile
+            # taken at the same time, beside the device's ops
+            with span(f"executor.{cat}", node=name, **attrs):
+                yield sp
         finally:
             sp.dur = (time.perf_counter() - self._epoch) - sp.t0
             stack.pop()
@@ -160,13 +182,6 @@ class Tracer:
             sp.attrs[key] = _scalarize(v)
         return [(site, float(c), int(cap))
                 for (site, _c, _cp), (c, cap) in zip(sink, sink_vals)]
-
-    # -- views -------------------------------------------------------------
-    def by_name(self) -> dict:
-        out: dict = {}
-        for sp in self.spans:
-            out.setdefault(sp.name, []).append(sp)
-        return out
 
 
 def _scalarize(v):
@@ -251,12 +266,6 @@ class RunTrace:
     plan_id: str = ""
 
     # -- views -------------------------------------------------------------
-    def span_for(self, node_id: str) -> Optional[Span]:
-        for sp in self.spans:
-            if sp.name == node_id:
-                return sp
-        return None
-
     def op_spans(self) -> list:
         return [sp for sp in self.spans if sp.cat not in ("run", "sync")]
 

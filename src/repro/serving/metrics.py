@@ -35,7 +35,13 @@ class RequestMetrics:
     first_token_at: float = 0.0
     finished_at: float = 0.0
     plan_ms: float = 0.0             # plan fetch/compile (cache hit ≈ free)
+    # the prefill call that served this request: its start (run clock,
+    # like joined_at), its whole latency (the same for every member of a
+    # batched prefill), its rows (pad rows included) and its requests
+    prefill_started_at: float = 0.0
     prefill_ms: float = 0.0
+    prefill_width: int = 0
+    prefill_members: int = 0
 
     @property
     def queue_wait_s(self) -> float:
@@ -269,10 +275,6 @@ class ServingMetrics:
     @property
     def queue_depth_samples(self) -> list:
         return self._queue_depth.samples
-
-    @property
-    def pool_fill_samples(self) -> list:
-        return self._pool_fill.samples
 
     def observe_tick(self, queue_depth: int, pool_fill: float) -> None:
         self.ticks += 1

@@ -33,6 +33,7 @@ import numpy as np
 
 from ..core.executor import plan_and_compile
 from ..core.faults import FaultInjectedError
+from ..core import tracing
 from ..core.ir import SystemCatalog
 from ..core.ledger import FlightRecorder, MemoryLedger, default_ledger
 from ..core.mqo import SubplanCache, mqo_run, subdag_keys
@@ -107,6 +108,14 @@ class AnalysisResult:
     deduped: bool = False            # rode an identical in-flight query
     batched: bool = False            # ran inside a vmapped batch
     ttfr_ms: float = 0.0             # submit -> first result
+    # time.perf_counter() stamps: run_analyses took the request in; the
+    # execution that produced the value (its own, its vmapped batch's, or
+    # the one its twin leader ran) started and finished; the result
+    # settled into its call's results.  None where nothing ran.
+    enqueued_at: Optional[float] = None
+    started_at: Optional[float] = None
+    finished_at: Optional[float] = None
+    settled_at: Optional[float] = None
 
     @property
     def ok(self) -> bool:
@@ -463,26 +472,31 @@ class AsyncServingRuntime:
         fwd, jitted, plan_ms = self._plan_prefill(bucket)
         rm.plan_ms = plan_ms
         t0 = time.perf_counter()
-        padded_np = np.zeros((1, bucket), np.int32)
-        padded_np[0, :req.prompt_len] = req.prompt
-        padded = jnp.asarray(padded_np)
-        outs, first_dev = jitted(self.params, padded,
-                                 jnp.int32(req.prompt_len))
-        # reserve prompt + the first decode write (position prompt_len is
-        # written by the first tick, before extend() is consulted)
-        self.pool.alloc(req.rid, req.prompt_len + 1)
-        if self.kv_mode:
-            self.pool.seed(req.rid, outs[1:], req.prompt_len)
-        else:
-            # replay fallback: families with recurrent state (mamba/rwkv)
-            # rebuild the prompt state through the cached decode path
-            c1 = init_cache(self.model, 1, self.max_seq)
-            for t in range(req.prompt_len):
-                _, c1 = self._dstep1(self.params, c1,
-                                     jnp.asarray(padded_np[:, t:t + 1]),
-                                     jnp.int32(t))
-            self.pool.adopt(req.rid, c1)
-        first = int(first_dev)
+        rm.prefill_started_at = t0 - self._t0
+        rm.prefill_width = rm.prefill_members = 1
+        with tracing.span("lm.prefill", rid=req.rid, bucket=bucket,
+                          members=1, width=1):
+            padded_np = np.zeros((1, bucket), np.int32)
+            padded_np[0, :req.prompt_len] = req.prompt
+            padded = jnp.asarray(padded_np)
+            outs, first_dev = jitted(self.params, padded,
+                                     jnp.int32(req.prompt_len))
+            # reserve prompt + the first decode write (position prompt_len
+            # is written by the first tick, before extend() is consulted)
+            self.pool.alloc(req.rid, req.prompt_len + 1)
+            if self.kv_mode:
+                self.pool.seed(req.rid, outs[1:], req.prompt_len)
+            else:
+                # replay fallback: families with recurrent state
+                # (mamba/rwkv) rebuild the prompt state through the cached
+                # decode path
+                c1 = init_cache(self.model, 1, self.max_seq)
+                for t in range(req.prompt_len):
+                    _, c1 = self._dstep1(self.params, c1,
+                                         jnp.asarray(padded_np[:, t:t + 1]),
+                                         jnp.int32(t))
+                self.pool.adopt(req.rid, c1)
+            first = int(first_dev)
         rm.prefill_ms = (time.perf_counter() - t0) * 1e3
         now = self._now()
         rm.joined_at = rm.first_token_at = now
@@ -569,12 +583,16 @@ class AsyncServingRuntime:
                 toks[i, 0, :req.prompt_len] = req.prompt
                 ns[i] = req.prompt_len
             t0 = time.perf_counter()
-            outs, firsts = vj(self.params, jnp.asarray(toks),
-                              jnp.asarray(ns))
-            firsts = np.asarray(firsts)
+            with tracing.span("lm.prefill",
+                              rids=" ".join(str(r.rid) for r, _ in batch),
+                              bucket=bucket, members=len(batch),
+                              width=width):
+                outs, firsts = vj(self.params, jnp.asarray(toks),
+                                  jnp.asarray(ns))
+                firsts = np.asarray(firsts)
+            # the call's whole latency: every member waited for all of it
             prefill_ms = (time.perf_counter() - t0) * 1e3
             self.registry.count("lm.batched_prefills", len(batch))
-            self.registry.summary("lm.prefill_batch").observe(len(batch))
         except Exception:
             for req, enq in batch:           # degrade to per-request prefill
                 try:
@@ -587,7 +605,9 @@ class AsyncServingRuntime:
                                 prompt_len=req.prompt_len, gen=req.gen,
                                 submitted_at=enq)
             rm.plan_ms = plan_mss[i]
-            rm.prefill_ms = prefill_ms / len(batch)
+            rm.prefill_ms = prefill_ms
+            rm.prefill_started_at = t0 - self._t0
+            rm.prefill_width, rm.prefill_members = width, len(batch)
             self.pool.alloc(req.rid, req.prompt_len + 1)
             kv_i = jax.tree.map(lambda x, _i=i: x[_i], outs[1:])
             self.pool.seed(req.rid, kv_i, req.prompt_len)
@@ -676,23 +696,28 @@ class AsyncServingRuntime:
                     self._decode_faults = 0
                 return True
         self._decode_faults = 0
-        toks = np.zeros((self.max_batch, 1), np.int32)
-        idxs = np.zeros((self.max_batch,), np.int32)
-        for st in active:
-            toks[st.slot, 0] = st.tok
-            idxs[st.slot] = st.pos
-        logits, self.pool.cache = self._dstep(
-            self.params, self.pool.cache, jnp.asarray(toks),
-            jnp.asarray(idxs))
-        logits = np.asarray(logits)
-        for st in active:
-            st.tok = int(np.argmax(logits[st.slot, 0, :self.cfg.vocab]))
-            st.pos += 1
-            st.out.append(st.tok)
-            if st.done:
-                self._finish(st, "ok")
-            elif not self.pool.extend(st.request.rid, st.pos + 1):
-                self._finish(st, "truncated")   # page budget exhausted
+        with tracing.span("lm.decode_step", active=len(active)):
+            with tracing.span("lm.decode.dispatch"):
+                toks = np.zeros((self.max_batch, 1), np.int32)
+                idxs = np.zeros((self.max_batch,), np.int32)
+                for st in active:
+                    toks[st.slot, 0] = st.tok
+                    idxs[st.slot] = st.pos
+                logits, self.pool.cache = self._dstep(
+                    self.params, self.pool.cache, jnp.asarray(toks),
+                    jnp.asarray(idxs))
+            with tracing.span("lm.decode.fetch"):
+                logits = np.asarray(logits)
+            with tracing.span("lm.decode.sample"):
+                for st in active:
+                    st.tok = int(np.argmax(
+                        logits[st.slot, 0, :self.cfg.vocab]))
+                    st.pos += 1
+                    st.out.append(st.tok)
+                    if st.done:
+                        self._finish(st, "ok")
+                    elif not self.pool.extend(st.request.rid, st.pos + 1):
+                        self._finish(st, "truncated")   # page budget gone
         return True
 
     # -- event loop ----------------------------------------------------------
@@ -782,16 +807,21 @@ class AsyncServingRuntime:
         """One analytical query through the cross-query CSE path (subplan
         cache attached) or plain execution; returns (value, frontier
         info)."""
-        if self.subplans is not None:
-            out, info = mqo_run(req.planned, req.params, req.inputs,
-                                cache=self.subplans,
-                                versions=req.store_versions,
-                                aux=req.aux, keys=keys, tied_to=req.tied_to)
-        else:
-            out = req.planned(req.params, req.inputs, aux=req.aux)
-            info = {"shared_hits": 0,
-                    "executed": len(req.planned.concrete.nodes)}
-        jax.block_until_ready(out)
+        with tracing.span("analysis.exec", rid=req.rid) as sp:
+            if self.subplans is not None:
+                out, info = mqo_run(req.planned, req.params, req.inputs,
+                                    cache=self.subplans,
+                                    versions=req.store_versions,
+                                    aux=req.aux, keys=keys,
+                                    tied_to=req.tied_to)
+            else:
+                out = req.planned(req.params, req.inputs, aux=req.aux)
+                info = {"shared_hits": 0,
+                        "executed": len(req.planned.concrete.nodes)}
+            sp.set_metadata(executed=info["executed"],
+                            shared_hits=info["shared_hits"])
+            with tracing.span("analysis.device_wait"):
+                jax.block_until_ready(out)
         return out, info
 
     @staticmethod
@@ -834,8 +864,10 @@ class AsyncServingRuntime:
         def one(pv):
             return planned(params, {**fixed, bp: pv}, aux=aux)
 
-        outs = jax.vmap(one)(stacked)
-        jax.block_until_ready(outs)
+        with tracing.span("analysis.batch", n=len(leaders),
+                          rids=" ".join(str(r.rid) for r in leaders)):
+            outs = jax.vmap(one)(stacked)
+            jax.block_until_ready(outs)
         vals = [jax.tree.map(lambda x, _i=i: x[_i], outs)
                 for i in range(len(leaders))]
         self.registry.count("analytics.batched", len(leaders))
@@ -854,7 +886,8 @@ class AsyncServingRuntime:
         # have drained a concurrent call's request); fall back to the
         # draining call's dict for requests with no registered owner
         sink, st0 = self._analysis_sinks.get(id(req), (results, t0))
-        res.ttfr_ms = (time.perf_counter() - st0) * 1e3
+        res.enqueued_at, res.settled_at = st0, time.perf_counter()
+        res.ttfr_ms = (res.settled_at - st0) * 1e3
         self.registry.summary("analytics.ttfr_ms").observe(res.ttfr_ms)
         self.registry.count("analytics.requests")
         sink[req.rid] = res
@@ -864,36 +897,43 @@ class AsyncServingRuntime:
         """One admission tick: key every drained query, dedupe exact twins
         (intra-tick groups + cross-task in-flight futures), coalesce
         same-shape queries into vmapped batches, run the rest through the
-        CSE path, and resolve every request with a result."""
+        CSE path, and resolve every request with a result.  The
+        ``analysis.tick`` span covers the admission decision (keying,
+        single-flight lookup, grouping), which runs before the tick's
+        first await; execution opens spans of its own."""
         loop = asyncio.get_running_loop()
         groups: dict = {}        # root key -> [(req, keys), ...]
         waiters: list = []       # (req, future of an in-flight twin)
-        for req in tick:
-            keys = subdag_keys(req.planned, req.inputs,
-                               versions=req.store_versions,
-                               params=req.params)
-            root = self._root_key(req, keys)
-            fut = self._analysis_inflight.get(root)
-            if fut is not None and root not in groups:
-                waiters.append((req, fut))
-                continue
-            groups.setdefault(root, []).append((req, keys))
-        # same-shape batching among group leaders (>=2 make a batch)
-        singles, shaped = [], {}
-        for root, members in groups.items():
-            leader = members[0][0]
-            if leader.batch_param is not None \
-                    and leader.batch_param in leader.inputs:
-                gk = self._batch_group_key(leader, members[0][1])
-                shaped.setdefault(gk, []).append((root, members))
-            else:
-                singles.append((root, members))
-        vbatches = []
-        for g in shaped.values():
-            if len(g) >= 2:
-                vbatches.append(g)
-            else:
-                singles.extend(g)
+        with tracing.span("analysis.tick", n=len(tick)) as sp:
+            for req in tick:
+                keys = subdag_keys(req.planned, req.inputs,
+                                   versions=req.store_versions,
+                                   params=req.params)
+                root = self._root_key(req, keys)
+                fut = self._analysis_inflight.get(root)
+                if fut is not None and root not in groups:
+                    waiters.append((req, fut))
+                    continue
+                groups.setdefault(root, []).append((req, keys))
+            # same-shape batching among group leaders (>=2 make a batch)
+            singles, shaped = [], {}
+            for root, members in groups.items():
+                leader = members[0][0]
+                if leader.batch_param is not None \
+                        and leader.batch_param in leader.inputs:
+                    gk = self._batch_group_key(leader, members[0][1])
+                    shaped.setdefault(gk, []).append((root, members))
+                else:
+                    singles.append((root, members))
+            vbatches = []
+            for g in shaped.values():
+                if len(g) >= 2:
+                    vbatches.append(g)
+                else:
+                    singles.extend(g)
+            sp.set_metadata(groups=len(groups),
+                            twins=len(tick) - len(groups),
+                            batches=len(vbatches))
         futs = {}
         for root, _ in singles:
             futs[root] = self._analysis_inflight[root] = loop.create_future()
@@ -903,7 +943,8 @@ class AsyncServingRuntime:
                     loop.create_future()
 
         def resolve(root, members, payload, *, batched=False):
-            status, val, info, err = payload
+            # payload: (status, value, info, error, (started, finished))
+            status, val, info, err, (started, finished) = payload
             fut = futs[root]
             if not fut.done():
                 fut.set_result(payload)
@@ -915,16 +956,20 @@ class AsyncServingRuntime:
                     req.rid, val, status, err,
                     shared_hits=info.get("shared_hits", 0),
                     executed=info.get("executed", 0),
-                    deduped=j > 0, batched=batched)
+                    deduped=j > 0, batched=batched,
+                    started_at=started, finished_at=finished)
                 self._settle_analysis(req, res, results, t0)
 
         for g in vbatches:
             leaders = [members[0][0] for _, members in g]
             try:
+                started = time.perf_counter()
                 vals = self._run_batched_group(leaders)
+                ran = (started, time.perf_counter())
                 for (root, members), val in zip(g, vals):
                     resolve(root, members,
-                            ("ok", val, {"executed": 1}, None), batched=True)
+                            ("ok", val, {"executed": 1}, None, ran),
+                            batched=True)
             except Exception as exc:
                 # vmap refused the plan (data-dependent shapes, host
                 # callbacks): run each leader through the CSE path instead
@@ -933,23 +978,27 @@ class AsyncServingRuntime:
                 singles.extend(g)
         for root, members in singles:
             leader, lkeys = members[0]
+            started = time.perf_counter()
             try:
                 val, info = self._analysis_exec(leader, keys=lkeys)
-                resolve(root, members, ("ok", val, info, None))
+                resolve(root, members, ("ok", val, info, None,
+                                        (started, time.perf_counter())))
             except Exception as exc:
                 err = {"reason": "analysis_failed",
                        "plan_id": getattr(leader.planned, "plan_id", ""),
                        "error": repr(exc)}
+                ran = (started, time.perf_counter())
                 self.recorder.trip("executor_error",
                                    {**err, **self._trip_context()})
-                resolve(root, members, ("error", None, {}, err))
+                resolve(root, members, ("error", None, {}, err, ran))
             await asyncio.sleep(0)   # let twins land on the future map
         for req, fut in waiters:
-            status, val, info, err = await fut
+            status, val, info, err, (started, finished) = await fut
             self.registry.count("analytics.deduped")
             res = AnalysisResult(req.rid, val, status, err,
                                  shared_hits=info.get("shared_hits", 0),
-                                 deduped=True)
+                                 deduped=True, started_at=started,
+                                 finished_at=finished)
             self._settle_analysis(req, res, results, t0)
 
     async def run_analyses(self, requests: Sequence[AnalysisRequest],
@@ -1056,8 +1105,6 @@ class AsyncServingRuntime:
                 tr = planned.last_run_trace
                 self.registry.summary("analytics.trace_wall_ms").observe(
                     tr.wall_ms)
-                self.registry.summary("analytics.sync_ms").observe(
-                    tr.sync_ms)
                 self.registry.count("analytics.traced")
             elif self.subplans is not None and planned.faults is None:
                 # cross-query CSE: reuse cached sub-DAG intermediates and
