@@ -772,7 +772,8 @@ def predicted_resident_bytes(t) -> Optional[int]:
     for a value of type ``t`` — ``bytesize()`` (capacity-derived: padded
     columns + validity, CSR arrays, COO postings) plus the shard-local
     block payloads a partitioned store keeps alongside the replicated
-    structure.  The MemoryLedger compares this against the measured
+    structure, or the doc-major padded postings (ELL) of an unpartitioned
+    corpus.  The MemoryLedger compares this against the measured
     ``tree_bytes`` of the actual payload; the tri-store benchmark enforces
     2x agreement."""
     base = t.bytesize() if hasattr(t, "bytesize") else None
@@ -787,5 +788,13 @@ def predicted_resident_bytes(t) -> Optional[int]:
     elif isinstance(t, CorpusT) and getattr(t, "partitioning", None):
         # doc-block payload: blk_doc_local + blk_term_ids (int32) + blk_tf
         # (f32) padded per partition ~ postings total
+        extra = t.postings * 12
+    elif isinstance(t, CorpusT):
+        # doc-major padded postings (ell_term int32 + ell_tf f32): 8 B a
+        # slot, 1 to ELL_MAX_SLOTS_PER_POSTING (4) slots a posting, none
+        # when the corpus fails that width rule.  12 B more a posting
+        # (24 B predicted) stays within 2x of each case: no ELL (12 B a
+        # posting held), the rule's limit (44 B), and the ~2 slots a
+        # posting of equally wide documents (~28 B)
         extra = t.postings * 12
     return int(base + extra)

@@ -81,6 +81,8 @@ class Span:
     span_id: int = 0
     parent_id: Optional[int] = None
     attrs: dict = field(default_factory=dict)
+    # the profiler annotation the span entered, while it is open
+    annotation: Any = field(default=None, repr=False, compare=False)
 
     @property
     def dur_ms(self) -> float:
@@ -136,23 +138,28 @@ class Tracer:
         try:
             # the module-level entry point: the span shows in a profile
             # taken at the same time, beside the device's ops
-            with span(f"executor.{cat}", node=name, **attrs):
+            with span(f"executor.{cat}", node=name, **attrs) as ann:
+                sp.annotation = ann
                 yield sp
         finally:
+            sp.annotation = None
             sp.dur = (time.perf_counter() - self._epoch) - sp.t0
             stack.pop()
             with self._lock:
                 self.spans.append(sp)
 
     def annotate(self, **attrs) -> None:
-        """Attach attrs to the innermost open span of the calling thread
-        (store impls report dist strategy / collective bytes this way
-        without knowing which physical node wraps them)."""
+        """Attach attrs to the innermost open span of the calling thread,
+        and to its profiler annotation (store impls report dist strategy,
+        collective bytes and postings layout this way without knowing
+        which physical node wraps them)."""
         if not self.enabled:
             return
         stack = self._stack()
         if stack:
             stack[-1].attrs.update(attrs)
+            if stack[-1].annotation is not None:
+                stack[-1].annotation.set_metadata(**attrs)
 
     def defer(self, key: str, value) -> None:
         """Record a device-side observation against the innermost open

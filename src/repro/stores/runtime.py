@@ -38,8 +38,8 @@ from .sharded import (_shardable, coll_all_to_all_bytes, coll_allgather_bytes,
                       sharded_count, sharded_expand, sharded_group_agg,
                       sharded_pagerank, sharded_partitioned_join,
                       sharded_tfidf_topk)
-from .text_store import (masked_topk, tfidf_scores, tfidf_topk,
-                         tfidf_topk_blockskip, tfidf_topk_masked)
+from .text_store import (ell_select_applies, masked_topk, tfidf_scores,
+                         tfidf_topk, tfidf_topk_blockskip, tfidf_topk_masked)
 
 _XLA = get_engine("xla")
 _PALLAS = get_engine("pallas")
@@ -489,6 +489,20 @@ def _i_tricount(ctx, args, node):
 # --------------------------------------------------------------------------
 
 
+def _note_scoring(ctx, corpus, query):
+    """EXPLAIN ANALYZE attribution of a dense TF-IDF scoring: the span's
+    ``layout`` is the postings layout the corpus holds (``ell`` or
+    ``coo``), and ``ell_select``, deferred like a BoundedRel count, is 1
+    where the scoring took the ELL select path and 0 where it took the COO
+    gather + scatter.  A no-op when tracing is off."""
+    tr = None if ctx is None else getattr(ctx, "tracer", None)
+    if tr is None or not tr.enabled:
+        return
+    tr.annotate(layout="ell" if "ell_term" in corpus else "coo")
+    tr.defer("ell_select",
+             ell_select_applies(corpus, query).astype(jnp.int32))
+
+
 def _topk_rel(ids, scores, valid):
     """Top-k results are a BoundedRel by construction: the valid slots form
     a prefix, so the traced count is the true result size (what the old
@@ -503,6 +517,7 @@ def _i_text_topk(ctx, args, node):
         # pushed candidate-doc mask, dense realization: score the whole
         # corpus, then mask + top-k (the bitwise reference the skipping
         # candidates must reproduce)
+        _note_scoring(ctx, args[0], args[1])
         return _topk_rel(*tfidf_topk_masked(args[0], args[1], args[2], k))
     c, mesh = args[0], getattr(ctx, "mesh", None)
     if (node.attrs.get("dist") == "doc" and "blk_doc_local" in c
@@ -514,7 +529,8 @@ def _i_text_topk(ctx, args, node):
         _annotate(ctx, dist="doc", coll="all_gather",
                   coll_bytes=coll_allgather_bytes(n * k * 8, n))
         return _topk_rel(*sharded_tfidf_topk(c, args[1], k, mesh))
-    return _topk_rel(*tfidf_topk(args[0], args[1], k))
+    _note_scoring(ctx, c, args[1])
+    return _topk_rel(*tfidf_topk(c, args[1], k))
 
 
 @TEXT_ENGINE.impl("text_topk_skip_inv")
@@ -539,6 +555,7 @@ def _i_text_topk_pallas(ctx, args, node):
 
 @TEXT_ENGINE.impl("text_scores_inv")
 def _i_text_scores(ctx, args, node):
+    _note_scoring(ctx, args[0], args[1])
     return tfidf_scores(args[0], args[1])
 
 

@@ -2,18 +2,23 @@
 
 The corpus is stored as the COO of its term-document matrix — one
 ``(doc_id, term_id, tf)`` triple per posting — plus per-document lengths
-and the idf table.  Scoring a dense query vector is then one gather + one
+and the idf table.  Scoring a dense query vector is one gather + one
 segment-sum over the postings (static shapes, jittable):
 
     score[d] = Σ_{postings (d, t)}  q[t] · idf[t] · tf[d,t] / len[d]
 
-followed by ``lax.top_k`` over documents.  The result is handed back as a
+followed by ``lax.top_k`` over documents.  An unsharded corpus whose
+documents are about equally wide also keeps its postings doc-major, padded
+to the widest document (ELL, ``[width, n_docs]``): a keyword query of at
+most :data:`QUERY_TERMS` distinct terms then scores every slot by comparing
+its term with the query's terms, with no postings-long gather and no
+scatter (:func:`_tfidf_scores_ell`).  The result is handed back as a
 *relation* (a (k,)-row table of ``doc``/``score``) — cross-engine by
 construction, which is what the planner's ``xfer`` placement operates on.
 """
 from __future__ import annotations
 
-from typing import Iterable, Sequence
+from typing import Iterable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +26,18 @@ import numpy as np
 
 from ..core.ir import CorpusT, ValidationError
 from ..core.ledger import register_store_payload
+
+# the keyword-query width the ELL select path serves: queries with at most
+# this many distinct terms (the benchmark's and the paper's have <= 6) score
+# by selects; wider ones take the COO gather + scatter
+QUERY_TERMS = 8
+# the ELL is built only while its slots stay within this many per posting
+# (a corpus of long, skewed documents keeps the COO path alone)
+ELL_MAX_SLOTS_PER_POSTING = 4
+# term id of an empty ELL slot, and of an unused query slot: neither matches
+# a real term (>= 0) nor the other
+_ELL_PAD_TERM = -1
+_NO_QUERY_TERM = -2
 
 
 class TextStore:
@@ -138,8 +155,43 @@ class TextStore:
         }
         if self.shards > 1:
             out.update(self._block_payload())
+        else:
+            ell = self.ell()
+            if ell is not None:
+                out["ell_term"] = jnp.asarray(ell[0])
+                out["ell_tf"] = jnp.asarray(ell[1])
         register_store_payload(self, out, "text_store")
         return out
+
+    def ell(self) -> Optional[tuple]:
+        """Doc-major padded postings ``(ell_term, ell_tf)``, each
+        ``[width, n_docs]`` (docs in the minor dimension: a TPU tiles the
+        width to 16 rows, where ``[n_docs, width]`` would pad it to 128
+        lanes), or None when the widest document's ``width`` would take
+        more than ``ELL_MAX_SLOTS_PER_POSTING`` slots per posting.  Doc
+        ``d``'s postings fill ``[0:count_d, d]`` in their stored order (a
+        stable sort by doc), so summing the slots in order adds exactly
+        what the COO segment-sum adds, in the same order; empty slots hold
+        ``tf`` 0 and a term that matches no query term."""
+        n, p = self.n_docs, self.n_postings
+        if p == 0:
+            return None
+        counts = np.bincount(self.doc_ids, minlength=n)
+        width = int(counts.max())
+        if width * n > ELL_MAX_SLOTS_PER_POSTING * p:
+            return None
+        if np.all(self.doc_ids[1:] >= self.doc_ids[:-1]):
+            order = slice(None)                 # already doc-major
+        else:
+            order = np.argsort(self.doc_ids, kind="stable")
+        docs = self.doc_ids[order]
+        starts = np.cumsum(counts) - counts
+        slot = np.arange(p) - starts[docs]
+        term = np.full((width, n), _ELL_PAD_TERM, np.int32)
+        tf = np.zeros((width, n), np.float32)
+        term[slot, docs] = self.term_ids[order]
+        tf[slot, docs] = self.tf[order]
+        return term, tf
 
     def _block_payload(self) -> dict:
         """Doc-block posting partition for shard-local scoring: shard d owns
@@ -182,13 +234,66 @@ class TextStore:
 # --------------------------------------------------------------------------
 
 
-def _tfidf_scores(corpus: dict, query):
+def _tfidf_scores_coo(corpus: dict, query):
+    """Scores from the COO postings: a gather per posting, a scatter-add
+    into the docs."""
     w = query.astype(jnp.float32) * corpus["idf"]
     contrib = (w[corpus["term_ids"]] * corpus["tf"]
                / corpus["doc_len"][corpus["doc_ids"]])
     n_docs = corpus["doc_len"].shape[0]
     return jax.ops.segment_sum(contrib, corpus["doc_ids"],
                                num_segments=n_docs)
+
+
+def _tfidf_scores_ell(corpus: dict, query):
+    """Scores from the ELL postings, exact for a query of at most
+    ``QUERY_TERMS`` distinct terms: each slot's weight is picked by
+    comparing its term with the query's terms (distinct, so at most one
+    matches and the pick is ``w[term]``), and each doc's slots are summed.
+    No postings-long gather, no scatter."""
+    # the first QUERY_TERMS nonzero entries, in term order (top_k keeps
+    # the lower index first among equals; jnp.nonzero would scatter)
+    present, terms = jax.lax.top_k((query != 0).astype(jnp.int32),
+                                   min(QUERY_TERMS, query.shape[-1]))
+    w = query.astype(jnp.float32) * corpus["idf"]
+    weights = w[terms]
+    terms = jnp.where(present > 0, terms, _NO_QUERY_TERM)
+    ell_term = corpus["ell_term"]
+    # a slot whose term is not in the query weighs 0, as its COO posting
+    # does; an empty slot adds 0 x 0 / doc_len
+    wt = jnp.zeros(ell_term.shape, jnp.float32)
+    for i in range(terms.shape[0]):
+        wt = jnp.where(ell_term == terms[i], weights[i], wt)
+    # one reduce over the slot axis: XLA reads each (8, 128) tile of the
+    # ELL once (a slice per slot would read it once per row of the tile),
+    # and the CPU adds the slots in order, as the segment-sum adds postings
+    scores = jnp.sum(wt * corpus["ell_tf"] / corpus["doc_len"], axis=0)
+    # a doc with no postings scores 0, as in the COO path, even where its
+    # length is 0 and its empty slots added 0 / 0
+    return jnp.where(ell_term[0] >= 0, scores, 0.0)
+
+
+def ell_select_applies(corpus: dict, query):
+    """Whether :func:`_tfidf_scores` takes the ELL select path for
+    ``query``: a traced bool (False when the corpus has no ELL)."""
+    if "ell_term" not in corpus:
+        return jnp.zeros((), jnp.bool_)
+    return jnp.count_nonzero(query) <= QUERY_TERMS
+
+
+@jax.jit
+def _tfidf_scores_adaptive(corpus: dict, query):
+    return jax.lax.cond(ell_select_applies(corpus, query), _tfidf_scores_ell,
+                        _tfidf_scores_coo, corpus, query)
+
+
+def _tfidf_scores(corpus: dict, query):
+    """The ELL select path where the corpus has an ELL and the query is a
+    keyword query (decided at run time, both branches compiled together),
+    else the COO path."""
+    if "ell_term" not in corpus:
+        return _tfidf_scores_coo(corpus, query)
+    return _tfidf_scores_adaptive(corpus, query)
 
 
 @jax.custom_batching.custom_vmap

@@ -177,6 +177,36 @@ def test_predicted_resident_bytes_shapes():
         assert isinstance(pred, int) and pred > 0
 
 
+def _corpus_bytes(case):
+    """(predicted, actual) resident bytes of a corpus: built at the test's
+    size, or reckoned at the deployment's (2^23 docs of 7-14 tokens,
+    59,000,000 postings, 2^16 terms, an ELL 14 slots wide)."""
+    from repro.core.ir import CorpusT
+    from repro.core.tracing import tree_bytes
+    rng = np.random.RandomState(1)
+    if case == "deployment":
+        docs, vocab, postings, width = 1 << 23, 1 << 16, 59_000_000, 14
+        actual = postings * 12 + docs * 4 + vocab * 4 + width * docs * 8
+        return predicted_resident_bytes(CorpusT(docs, vocab, postings)), \
+            actual
+    if case == "ell":
+        tx = TextStore.from_docs(
+            [rng.randint(0, 4096, rng.randint(7, 15)) for _ in range(3000)],
+            4096)
+    else:   # one wide doc: fails the ELL's width rule
+        tx = TextStore.from_docs(
+            [np.arange(500)] + [[t] for t in rng.randint(0, 500, 300)], 512)
+    pay = tx.payload()
+    assert ("ell_term" in pay) == (case == "ell")
+    return predicted_resident_bytes(tx.type), tree_bytes(pay)
+
+
+@pytest.mark.parametrize("case", ["ell", "no_ell", "deployment"])
+def test_corpus_prediction_within_2x_with_and_without_ell(case):
+    pred, actual = _corpus_bytes(case)
+    assert 0.5 <= actual / pred <= 2.0, (case, pred, actual)
+
+
 # --------------------------------------------------------------------------
 # plan cache: byte budget + stale-first-then-largest eviction
 # --------------------------------------------------------------------------

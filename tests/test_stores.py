@@ -20,7 +20,9 @@ from repro.stores.graph_store import (accumulator_lanes, expand_frontier,
                                       expand_frontier_blockskip,
                                       lane_segment_sum, pagerank,
                                       triangle_count)
-from repro.stores.text_store import tfidf_scores, tfidf_topk
+from repro.stores.text_store import (QUERY_TERMS, _tfidf_scores_coo,
+                                     _tfidf_scores_ell, ell_select_applies,
+                                     tfidf_scores, tfidf_topk)
 
 CAT = standard_catalog()
 SYS = SystemCatalog()
@@ -315,6 +317,121 @@ def test_tfidf_batched_matches_per_query(rng, batched_corpus):
     for i in range(3):
         want = tfidf_scores(corpus, qs[i])
         np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want))
+
+
+# --------------------------------------------------------------------------
+# doc-major padded postings (ELL) and the query-term select path
+# --------------------------------------------------------------------------
+
+ELL_VOCAB = 40
+
+
+def _ell_store(rng, layout):
+    """A corpus whose ELL the select path scores, built four ways: from
+    docs (postings sorted by doc), from shuffled postings, with docs that
+    hold no postings (one of length 0), and grown by ``append``.  Several
+    docs sit at the widest width."""
+    docs = [rng.choice(ELL_VOCAB, rng.randint(1, 7), replace=False)
+            for _ in range(30)]
+    docs += [rng.choice(ELL_VOCAB, 9, replace=False) for _ in range(3)]
+    if layout == "appended":
+        tx = TextStore.from_docs(docs[:20], ELL_VOCAB)
+        tx.payload()
+        return tx.append(docs[20:])
+    tx = TextStore.from_docs(docs, ELL_VOCAB)
+    if layout == "sorted":
+        return tx
+    perm = rng.permutation(tx.n_postings)
+    doc_ids, doc_len = tx.doc_ids[perm], tx.doc_len
+    if layout == "empty_docs":
+        # four more docs with no postings, one of them of length 0
+        doc_len = np.concatenate([doc_len, [3, 0, 1, 5]]).astype(np.float32)
+    return TextStore.from_postings(doc_ids, tx.term_ids[perm], tx.tf[perm],
+                                   doc_len, ELL_VOCAB)
+
+
+def _query(rng, n_terms):
+    """A query of exactly ``n_terms`` distinct terms, some repeated."""
+    terms = rng.choice(ELL_VOCAB, n_terms, replace=False)
+    q = np.zeros(ELL_VOCAB, np.float32)
+    q[terms] = rng.randint(1, 3, n_terms)
+    return jnp.asarray(q)
+
+
+@pytest.mark.parametrize("n_terms", [0, 1, QUERY_TERMS, QUERY_TERMS + 1])
+@pytest.mark.parametrize("layout",
+                         ["sorted", "unsorted", "empty_docs", "appended"])
+def test_ell_select_bitwise_equals_coo(rng, layout, n_terms):
+    tx = _ell_store(rng, layout)
+    pay = tx.payload()
+    width = int(np.bincount(tx.doc_ids, minlength=tx.n_docs).max())
+    assert pay["ell_term"].shape == pay["ell_tf"].shape == (width, tx.n_docs)
+    assert width == 9
+    q = _query(rng, n_terms)
+    want = np.asarray(_tfidf_scores_coo(pay, q))
+    assert bool(ell_select_applies(pay, q)) == (n_terms <= QUERY_TERMS)
+    np.testing.assert_array_equal(np.asarray(tfidf_scores(pay, q)), want)
+    if n_terms <= QUERY_TERMS:
+        np.testing.assert_array_equal(
+            np.asarray(_tfidf_scores_ell(pay, q)), want)
+    np.testing.assert_allclose(
+        want, R.tfidf_scores_ref(tx.doc_ids, tx.term_ids, tx.tf, tx.doc_len,
+                                 tx.idf, np.asarray(q)), rtol=1e-5)
+
+
+def test_ell_keeps_each_docs_postings_in_stored_order(rng):
+    tx = _ell_store(rng, "unsorted")
+    term, tf = tx.ell()
+    for d in range(tx.n_docs):
+        mine = tx.doc_ids == d
+        n = int(mine.sum())
+        np.testing.assert_array_equal(term[:n, d], tx.term_ids[mine])
+        np.testing.assert_array_equal(tf[:n, d], tx.tf[mine])
+        assert (term[n:, d] < 0).all() and (tf[n:, d] == 0).all()
+
+
+def test_ell_select_under_the_batched_map_rule(rng):
+    tx = _ell_store(rng, "unsorted")
+    pay = tx.payload()
+    qs = jnp.stack([_query(rng, n) for n in (3, QUERY_TERMS + 2, 0, 6)])
+    got = np.asarray(jax.vmap(tfidf_scores, in_axes=(None, 0))(pay, qs))
+    for i in range(qs.shape[0]):
+        np.testing.assert_array_equal(
+            got[i], np.asarray(_tfidf_scores_coo(pay, qs[i])))
+
+
+def test_wide_skewed_corpus_builds_no_ell(rng):
+    # one doc of 40 distinct terms among 30 one-term docs: 40 slots for
+    # each of 31 docs over 70 postings fails the 4-slots-a-posting rule
+    docs = [np.arange(ELL_VOCAB)] + [[t] for t in rng.randint(0, 40, 30)]
+    tx = TextStore.from_docs(docs, ELL_VOCAB)
+    pay = tx.payload()
+    assert tx.ell() is None and "ell_term" not in pay
+    q = _query(rng, 3)
+    assert not bool(ell_select_applies(pay, q))
+    np.testing.assert_array_equal(np.asarray(tfidf_scores(pay, q)),
+                                  np.asarray(_tfidf_scores_coo(pay, q)))
+
+
+def test_sharded_store_keeps_its_block_payload_and_no_ell(rng):
+    pay = _ell_store(rng, "sorted").with_shards(2).payload()
+    assert "blk_doc_local" in pay and "ell_term" not in pay
+
+
+def test_explain_analyze_reports_scoring_layout_and_path(rng):
+    table, graph, corpus = _small_social(rng)
+    fn = _tri_analysis(table, graph, corpus).compile(
+        SYS, engines=store_engines(), cache=False)
+    inputs = {"tweets": table.payload(), "g": graph.payload(),
+              "cx": corpus.payload()}
+    for terms, path in (([1, 2, 3], 1), (np.arange(QUERY_TERMS + 1), 0)):
+        q = jnp.asarray(corpus.query_vector(terms))
+        fn.analyze({}, {**inputs, "q": q})
+        spans = [s for s in fn.last_run_trace.op_spans()
+                 if "layout" in s.attrs]
+        assert len(spans) == 1
+        assert spans[0].attrs["layout"] == "ell"
+        assert spans[0].attrs["ell_select"] == path
 
 
 # --------------------------------------------------------------------------

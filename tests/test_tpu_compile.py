@@ -7,6 +7,7 @@ that is described, not attached, and refuses what the chip would refuse
 described inside a fixture, never while a module is imported: only one
 process at a time may load the TPU library.
 """
+import re
 import types
 
 import jax
@@ -22,7 +23,7 @@ from repro.stores.masked_kernels import (compact_prefix_pallas,
                                          join_probe_pallas,
                                          masked_segment_agg_pallas,
                                          masked_tfidf_pallas)
-from repro.stores.text_store import tfidf_scores
+from repro.stores.text_store import _tfidf_scores_ell, tfidf_scores
 
 # the deployment widths of chip_smoke.py's tri-model analysis
 TWEETS = 1 << 23
@@ -30,6 +31,7 @@ HASHTAGS = 1 << 18
 EDGES = 1 << 24
 POSTINGS = 59_000_000
 VOCAB = 1 << 16
+ELL_WIDTH = 14          # 7-14 tokens a doc: at most 14 distinct terms
 
 
 @pytest.fixture(scope="module")
@@ -189,3 +191,37 @@ def test_batched_tfidf_stays_query_major(one_chip):
     temp1 = one.memory_analysis().temp_size_in_bytes
     temp4 = four.memory_analysis().temp_size_in_bytes
     assert temp4 <= 2 * temp1 + 4 * 4 * docs, (temp1, temp4)
+
+
+def _deployment_corpus(one_chip):
+    """The smoke's whole corpus: COO postings, doc lengths, idf and the
+    doc-major padded postings (ELL)."""
+    i32 = lambda *shape: _spec(one_chip, shape, jnp.int32)   # noqa: E731
+    f32 = lambda *shape: _spec(one_chip, shape, jnp.float32)  # noqa: E731
+    return {"doc_ids": i32(POSTINGS), "term_ids": i32(POSTINGS),
+            "tf": f32(POSTINGS), "doc_len": f32(TWEETS), "idf": f32(VOCAB),
+            "ell_term": i32(ELL_WIDTH, TWEETS),
+            "ell_tf": f32(ELL_WIDTH, TWEETS)}
+
+
+def test_unmasked_tfidf_compiles_for_v5e_at_deployment_width(one_chip):
+    # both paths of the adaptive scoring, the select and the COO fallback
+    jax.jit(tfidf_scores).lower(
+        _deployment_corpus(one_chip),
+        _spec(one_chip, (VOCAB,), jnp.float32)).compile()
+
+
+def test_ell_select_holds_no_scatter_nor_long_gather(one_chip):
+    hlo = jax.jit(_tfidf_scores_ell).lower(
+        _deployment_corpus(one_chip),
+        _spec(one_chip, (VOCAB,), jnp.float32)).compile().as_text()
+    assert not re.search(r"\sscatter\(", hlo)
+    gathers = [ln for ln in hlo.splitlines() if re.search(r"\sgather\(", ln)]
+    assert gathers, "the query weights' gather is gone: check the reading"
+    for ln in gathers:
+        # no operand or index as long as the docs (or the postings)
+        for dims in re.findall(r"\b[a-z]+\d*\[([\d,]*)\]", ln):
+            n = 1
+            for d in filter(None, dims.split(",")):
+                n *= int(d)
+            assert n < TWEETS, ln
