@@ -111,6 +111,16 @@ def accelerator(chips: int):
     return devices[:chips]
 
 
+def check_chips(cfg: dict, cell: dict, devices) -> None:
+    """A configuration states its chips (``deployment.chips``); they have
+    to be its cell's, and attached."""
+    chips = cfg["deployment"]["chips"]
+    if chips != cell["chips"] or len(devices) < chips:
+        raise BenchError(
+            f"configuration {cfg['name']} runs on {chips} chip(s); the cell "
+            f"asks for {cell['chips']}, and {len(devices)} are given")
+
+
 def memory_peak(devices) -> int:
     return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)

@@ -4,7 +4,8 @@ control's, on several seeds, at the cell's own size.
     python bench/control.py --workload <cell> --seeds 1,2,3 --seconds 10
 
 The control is the plain reference put in the program's place, computed
-in the nearest precision below the one the configuration states:
+in the nearest precision below the one the configuration states.  Each
+driver supplies its own (``control`` in ``bench/drivers/<driver>.py``):
 
   * analysis cells (float32): the NumPy reference with every intermediate
     rounded to bfloat16, over the queries a run of the cell would draw,
@@ -30,43 +31,6 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path[0:1] = [str(ROOT), str(ROOT / "src")]
 
 
-def analysis_control(cfg, traffic, seed, n_queries=8):
-    from bench import generator, social
-    from bench.ref_analysis import Reference, analysis_error, bf16
-    s = cfg["deployment"]["data"]
-    data = social.build_social_data(
-        generator.run_rng(seed), tweets=s["tweets"], hashtags=s["hashtags"],
-        users=s["users"], vocab=s["vocab"],
-        doc_tokens=tuple(s["doc_tokens"])).cut(**s["cut"])
-    ref, low = Reference(data), Reference(data, rounding=bf16)
-    rng = generator.run_rng(seed, 4)
-    worst = []
-    for _ in range(n_queries):
-        q = social.query_vector(
-            generator.query_terms(traffic, rng, data.vocab), data.vocab)
-        want, kth, spare = ref.result(q)
-        worst.append(analysis_error(low.result(q)[0], want, kth, spare)[0])
-    # the number a run compares is the worst over its sample
-    return {"control_score_err": max(worst), "control_per_query": worst}
-
-
-def lm_control(args, bench, cell, seed):
-    from bench import common, ref_lm
-    from bench.run import execute
-    devices = common.accelerator(cell["chips"])
-    run = execute(argparse.Namespace(seed=seed, seconds=args.seconds,
-                                     trace=0),
-                  bench, cell, devices, common.CompileCounter())
-    cfg, params = run.config, run.extra["params"]
-    gaps = [ref_lm.control_gap(cfg, params, r["prompt"], r["tokens"])
-            for r in run.extra["sample"]]
-    return {"program_logit_gap": run.checks[0].value,
-            "control_logit_gap": max(gaps), "control_gaps": gaps,
-            "served_tokens": sum(len(r["tokens"])
-                                 for r in run.extra["sample"]),
-            "correct": run.correct}
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -77,15 +41,11 @@ def main(argv=None) -> int:
     from repro.core.backend import enable_compile_cache
     bench = common.manifest()
     cell = common.workload(bench, args.workload)
-    cfg = common.config_of(bench, cell)
-    traffic = common.traffic_of(cell)
+    drv = common.driver(common.config_of(bench, cell))
     enable_compile_cache()
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
-        if cfg["driver"] == "analysis":
-            out = analysis_control(cfg, traffic, seed)
-        else:
-            out = lm_control(args, bench, cell, seed)
+        out = drv.control(bench, cell, seed, args.seconds)
         print(json.dumps({"workload": cell["name"], "seed": seed, **out,
                           "seconds": time.perf_counter() - t0}), flush=True)
     return 0

@@ -7,6 +7,14 @@ and an ``AsyncServingRuntime`` holding a subplan cache.  The warm-up runs
 the single-query path twice, which fills the subplan cache with the
 query-independent graph half, as the traffic needs.
 
+Chips: the configuration states them (``deployment.chips``), and the
+harness holds them to the cell's (``common.check_chips``).  On one chip
+nothing is sharded.  On more, the stores are declared partitioned over
+them (``with_shards``), the plan is made for a ``("data", "model")`` mesh
+of shape (chips, 1), and the payloads are built on the host and placed
+once: row, dst-block and doc-block arrays split along the data axis
+(``shard_store_inputs``), the query vectors replicated.
+
 Window: every analysis goes through ``run_analyses``, one call per
 request, so concurrent calls share the runtime's tenant scheduler,
 single-flight map and vmapped batches.  The traffic is an open loop of
@@ -52,16 +60,43 @@ def tree_nbytes(tree) -> int:
                if hasattr(x, "nbytes"))
 
 
+def placement(payloads: dict) -> list:
+    """Where each payload leaf lives: its path, global shape, the devices
+    holding a part of it, whether it is split or replicated, the rows of
+    its first part, and its bytes on each device."""
+    import jax
+    out = []
+    for name, tree in payloads.items():
+        for path, x in jax.tree_util.tree_leaves_with_path(tree):
+            shards = getattr(x, "addressable_shards", None)
+            if shards is None:
+                continue
+            per = {}
+            for sh in shards:
+                per[sh.device.id] = per.get(sh.device.id, 0) \
+                    + int(sh.data.nbytes)
+            out.append({
+                "leaf": name + jax.tree_util.keystr(path),
+                "shape": list(x.shape), "devices": len(per),
+                "split": not x.sharding.is_fully_replicated,
+                "shard_rows": (int(shards[0].data.shape[0]) if x.ndim
+                               else None),
+                "bytes": per})
+    return out
+
+
 class Cell:
     """The program's side of one run: stores, plan, runtime, requests."""
 
-    def __init__(self, cfg: dict, traffic: dict, seed: int):
+    def __init__(self, cfg: dict, traffic: dict, seed: int, devices):
         import jax
         import jax.numpy as jnp
         from repro.configs.base import get_smoke_config
         from repro.core.adil_parser import parse_adil
         from repro.core.ir import standard_catalog
         from repro.core.ledger import FlightRecorder
+        from repro.launch.mesh import (replicated_sharding,
+                                       shard_store_inputs, syscat_for_mesh)
         from repro.models import build_model
         from repro.serving import AnalysisRequest, AsyncServingRuntime
         from repro.stores import store_engines
@@ -69,36 +104,70 @@ class Cell:
         self.jnp, self.Request = jnp, AnalysisRequest
         self.cfg, self.traffic = cfg, traffic
         dep = cfg["deployment"]
+        chips = dep["chips"]
+        self.mesh = None
+        if chips > 1:
+            self.mesh = jax.sharding.Mesh(
+                np.asarray(devices[:chips]).reshape(chips, 1),
+                ("data", "model"))
+            self._replicated = replicated_sharding(self.mesh)
         t0 = common.now()
-        cut = cfg["deployment"]["data"]["cut"]
+        cut = dep["data"]["cut"]
         self.data = social.build_social_data(
             generator.run_rng(seed), **self._sizes(cfg)).cut(**cut)
+        t_data = common.now()
         stores = social.build_stores(self.data)
-        self.payloads = {n: s.payload() for n, s in
-                         zip(("tweets", "g", "cx"), stores)}
+        names = ("tweets", "g", "cx")
+        if self.mesh is None:
+            self.payloads = {n: s.payload() for n, s in zip(names, stores)}
+            t_built = common.now()
+        else:
+            stores = [s.with_shards(chips) for s in stores]
+            # built on the host's CPU device, then placed once
+            with jax.default_device(jax.devices("cpu")[0]):
+                built = {n: jax.tree.map(np.asarray, s.payload())
+                         for n, s in zip(names, stores)}
+            t_built = common.now()
+            self.payloads = shard_store_inputs(self.mesh, built)
+            del built
         jax.block_until_ready(self.payloads)
+        t_placed = common.now()
+        self.placement = placement(self.payloads)
+        per_chip = {}
+        for leaf in self.placement:
+            for d, b in leaf["bytes"].items():
+                per_chip[d] = per_chip.get(d, 0) + b
         common.log(f"data from the seed: {self.data.tweets} tweets, "
                    f"{stores[1].n_edges} co-mention edges, "
                    f"{stores[2].n_postings} postings; resident "
                    + ", ".join(f"{n} {tree_nbytes(p)} B"
                                for n, p in self.payloads.items())
-                   + f"; {common.now() - t0:.1f} s")
+                   + f"; per chip {[per_chip[d] for d in sorted(per_chip)]}"
+                   f" B; data {t_data - t0:.1f} s, stores and layouts "
+                   f"{t_built - t_data:.1f} s, transfer "
+                   f"{t_placed - t_built:.1f} s")
         lm = build_model(get_smoke_config(dep["runtime_lm"]))
         lm_params, _ = lm.init_params(jax.random.key(0))
         self.rt = AsyncServingRuntime(
             lm, lm_params, recorder=FlightRecorder(capacity=4096),
             subplan_budget=dep["subplan_budget_bytes"],
-            analysis_tick=dep["analysis_tick"])
+            analysis_tick=dep["analysis_tick"],
+            syscat=None if self.mesh is None else syscat_for_mesh(self.mesh))
         analysis = parse_adil(social.adil_script(*stores), standard_catalog())
-        for name, store in zip(("tweets", "g", "cx"), stores):
+        for name, store in zip(names, stores):
             analysis.bind(name, store)
+        t_plan = common.now()
         self.fn = analysis.compile(
-            self.rt.syscat,
+            self.rt.syscat, mesh=self.mesh,
             engines=store_engines(pallas="pallas" in dep["engines"]))
         self.versions = analysis.store_versions()
         chosen = sorted({r["chosen"] for r in self.fn.report
                          if r["pattern"] != "xfer_op"})
-        common.log(f"plan: {chosen}")
+        dist = sorted({(n.impl, n.attrs["dist"])
+                       for n in self.fn.concrete.topo()
+                       if n.attrs.get("dist")})
+        common.log(f"plan: {chosen}; sharded nodes {dist}; planned in "
+                   f"{common.now() - t_plan:.1f} s")
         self.rid = 0
 
     @staticmethod
@@ -109,9 +178,13 @@ class Cell:
                     doc_tokens=tuple(s["doc_tokens"]))
 
     def query(self, terms):
-        """(host vector, device vector) of a query's terms."""
+        """(host vector, device vector) of a query's terms; on a mesh the
+        device vector is replicated."""
         v = social.query_vector(terms, self.data.vocab)
-        return v, self.jnp.asarray(v)
+        if self.mesh is None:
+            return v, self.jnp.asarray(v)
+        import jax
+        return v, jax.device_put(v, self._replicated)
 
     def request(self, qdev, *, batch: bool, tenant: int):
         self.rid += 1
@@ -141,13 +214,16 @@ def warm_up(cell: Cell, warm_rng) -> None:
     drains its tenant queue as soon as a call enqueues, so the window
     never forms a vmapped batch: no batch width is warmed."""
     vocab = cell.data.vocab
-    for _ in range(2):
+    for i in range(2):
         _, qd = cell.query(generator.query_terms(cell.traffic, warm_rng,
                                                  vocab))
+        t0 = common.now()
         res = cell.serve([cell.request(qd, batch=False, tenant=0)], 600)
         if not res[0].ok:
             raise common.BenchError(f"warm-up analysis failed: "
                                     f"{res[0].error}")
+        common.log(f"warm-up analysis {i}: {common.now() - t0:.2f} s, "
+                   f"{res[0].executed} plan nodes executed")
 
 
 async def _open_loop(cell, arrivals, pool, recs, t0, seconds, prof, chips):
@@ -179,12 +255,19 @@ async def _open_loop(cell, arrivals, pool, recs, t0, seconds, prof, chips):
 
 def run(run: common.Run, ctx: common.Ctx) -> None:
     cfg, traffic, seconds = run.config, run.traffic, ctx.seconds
-    cell = Cell(cfg, traffic, ctx.seed)
+    cell = Cell(cfg, traffic, ctx.seed, ctx.devices)
     for attr, name in (("_analysis_exec", "bench.analysis_exec"),
                        ("_run_batched_group", "bench.vmapped_batch")):
         common.annotate(cell.rt, attr, name)
     warm_rng = generator.run_rng(ctx.seed, 3)
+    programs = ctx.counter.programs
     warm_up(cell, warm_rng)
+    in_use = [(d.memory_stats() or {}).get("bytes_in_use")
+              for d in ctx.devices]
+    common.log(f"warm-up: programs compiled or loaded "
+               f"{ctx.counter.programs - programs}; bytes in use per chip "
+               f"{in_use}")
+    run.extra["placement"] = cell.placement
 
     query_rng = generator.run_rng(ctx.seed, 4)
     vocab = cell.data.vocab
@@ -282,3 +365,53 @@ def _sample(ok: list, seed: int, per_route: int) -> list:
                               replace=False)
             out.extend(rs[i] for i in sorted(pick))
     return out
+
+
+# --------------------------------------------------------------------------
+# what every driver supplies beside ``run``: its tiny size, its requests
+# due in a window, and its control
+# --------------------------------------------------------------------------
+
+TINY_DATA = dict(tweets=1 << 13, hashtags=1 << 8, users=1 << 10,
+                 vocab=1 << 9, doc_tokens=[7, 15])
+TINY_CUT = dict(postings=56000, edges=6000)
+
+
+def tiny(cfg: dict, traffic: dict) -> dict:
+    """Cut a configuration and its traffic, in place, to a size the CPU
+    runs in seconds (the benchmark's own tests); returns the functions of
+    this module that a tiny run replaces (none).  Arrivals come at 40/s
+    over the chips: the CPU's devices share its cores."""
+    cfg["deployment"]["data"] = dict(TINY_DATA, cut=TINY_CUT)
+    cfg["deployment"]["analysis_tick"] = 4
+    if "rate_per_s" in traffic:
+        traffic["rate_per_s"] = 40.0 / cfg["deployment"]["chips"]
+    return {}
+
+
+def due_in_window(traffic: dict, seconds: float) -> int:
+    """Analyses due in a window of ``seconds``."""
+    return len(generator.analysis_arrivals(traffic, seconds))
+
+
+def control(bench: dict, cell: dict, seed: int, seconds: float,
+            n_queries: int = 8) -> dict:
+    """The control's reading on ``seed``: the NumPy reference with every
+    intermediate rounded to bfloat16, over the queries a run of the cell
+    would draw, compared with the float64 reference as a run compares the
+    program (``seconds`` is not used: no window is needed)."""
+    from bench.ref_analysis import bf16
+    cfg, traffic = common.config_of(bench, cell), common.traffic_of(cell)
+    data = social.build_social_data(
+        generator.run_rng(seed), **Cell._sizes(cfg)).cut(
+            **cfg["deployment"]["data"]["cut"])
+    ref, low = Reference(data), Reference(data, rounding=bf16)
+    rng = generator.run_rng(seed, 4)
+    worst = []
+    for _ in range(n_queries):
+        q = social.query_vector(
+            generator.query_terms(traffic, rng, data.vocab), data.vocab)
+        want, kth, spare = ref.result(q)
+        worst.append(analysis_error(low.result(q)[0], want, kth, spare)[0])
+    # the number a run compares is the worst over its sample
+    return {"control_score_err": max(worst), "control_per_query": worst}

@@ -22,6 +22,7 @@ best is compared with its limit.
 """
 from __future__ import annotations
 
+import argparse
 import gc
 
 from bench import common, generator, ref_lm
@@ -236,3 +237,59 @@ def _sample(ok: list, seed: int, n: int) -> list:
     rng = generator.run_rng(seed, 5)
     pick = rng.choice(len(rest), min(n - 1, len(rest)), replace=False)
     return [longest] + [rest[i] for i in sorted(pick)]
+
+
+# --------------------------------------------------------------------------
+# what every driver supplies beside ``run``: its tiny size, its requests
+# due in a window, and its control
+# --------------------------------------------------------------------------
+
+TINY_LM = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
+               num_key_value_heads=2, head_dim=16, intermediate_size=128,
+               vocab_size=512)
+
+
+def smoke_model(cfg: dict):
+    """The program's smoke-size model of ``cfg``'s architecture (the sizes
+    of :data:`TINY_LM`), in the configuration's ``param_dtype``."""
+    from repro.configs.base import get_smoke_config
+    from repro.models import build_model
+    return build_model(with_deployment(
+        get_smoke_config(cfg["deployment"]["arch"]), cfg))
+
+
+def tiny(cfg: dict, traffic: dict) -> dict:
+    """Cut a configuration and its traffic, in place, to a size the CPU
+    runs in seconds (the benchmark's own tests); returns the functions of
+    this module that a tiny run replaces: the program's model is its
+    smoke size, which the published widths would refuse."""
+    cfg.update(TINY_LM)
+    cfg["deployment"].update(max_batch=4, max_seq=128)
+    traffic.update(rate_per_s=4.0, max_total=128)
+    traffic["prompt"].update(median=16, min=4, max=64)
+    traffic["output"].update(median=8, min=2, max=32)
+    return {"program_model": smoke_model}
+
+
+def due_in_window(traffic: dict, seconds: float) -> int:
+    """Requests due in a window of ``seconds``."""
+    return len(generator.lm_lengths(traffic, seconds))
+
+
+def control(bench: dict, cell: dict, seed: int, seconds: float) -> dict:
+    """One whole run of the cell on ``seed`` (its window of ``seconds``),
+    and the control's reading on what it served: the reference forward
+    with every matmul operand rounded to scaled float8 (e4m3), the gap of
+    its first choice at each served position."""
+    from bench.run import execute
+    devices = common.accelerator(cell["chips"])
+    run = execute(argparse.Namespace(seed=seed, seconds=seconds, trace=0),
+                  bench, cell, devices, common.CompileCounter())
+    cfg, params = run.config, run.extra["params"]
+    gaps = [ref_lm.control_gap(cfg, params, r["prompt"], r["tokens"])
+            for r in run.extra["sample"]]
+    return {"program_logit_gap": run.checks[0].value,
+            "control_logit_gap": max(gaps), "control_gaps": gaps,
+            "served_tokens": sum(len(r["tokens"])
+                                 for r in run.extra["sample"]),
+            "correct": run.correct}

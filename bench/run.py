@@ -75,6 +75,7 @@ def execute(args, bench, cell, devices, counter):
     """Set up, measure and check one run; returns the filled ``Run``."""
     from bench import common
     cfg = common.config_of(bench, cell)
+    common.check_chips(cfg, cell, devices)
     run = common.Run(cell=cell, config=cfg, traffic=common.traffic_of(cell),
                      seconds=args.seconds,
                      peaks=common.peaks(devices[0].device_kind))

@@ -3,7 +3,7 @@ percentiles."""
 import pytest
 
 from bench import common, flops
-from bench.tests.tiny import TINY_LM
+from bench.drivers.lm import TINY_LM
 from bench.common import load_json
 
 QWEN3 = load_json(common.BENCH / "configs" / "qwen3-0.6b.json")

@@ -9,9 +9,9 @@ import numpy as np
 import pytest
 
 from bench import common, generator, ref_lm, social
-from bench.drivers.analysis import hidden_failures
+from bench.drivers.analysis import TINY_DATA, hidden_failures
+from bench.drivers.lm import TINY_LM
 from bench.ref_analysis import Reference, analysis_error, score_error
-from bench.tests.tiny import TINY_DATA, TINY_LM
 
 SIZES = dict(TINY_DATA, doc_tokens=tuple(TINY_DATA["doc_tokens"]))
 
@@ -131,7 +131,7 @@ def test_hidden_failures_are_the_smokes(smoke):
 
 
 def test_every_seed_gives_the_same_shapes_after_the_cut():
-    from bench.tests.tiny import TINY_CUT
+    from bench.drivers.analysis import TINY_CUT
     types = []
     for seed in (2**31 + 1, 2**32 + 3):
         d = social.build_social_data(generator.run_rng(seed), **SIZES)

@@ -6,18 +6,33 @@ drivers' own code."""
 import numpy as np
 import pytest
 
-from bench import social
+from bench import common, social
 from bench.ref_analysis import Reference, analysis_error, bf16
 from bench.tests import tiny
 
-ANALYSIS_CELLS = ("pulse-8m.mixed",)
+BENCH = common.manifest()
+ANALYSIS_CELLS = [w["name"] for w in BENCH["workloads"]
+                  if common.config_of(BENCH, w)["driver"] == "analysis"]
+# and a cell the manifest does not hold: the first one's configuration on
+# four chips, as a sharded deployment would declare it
+CELLS = ANALYSIS_CELLS + [(ANALYSIS_CELLS[0], 4)]
 
 
-@pytest.mark.parametrize("cell", ANALYSIS_CELLS)
-def test_sound_analysis_run_is_correct(cell):
+@pytest.mark.parametrize("cell", CELLS, ids=tiny.label)
+def test_sound_analysis_run_is_correct(cell, request):
+    if tiny.elsewhere(request, cell):
+        return
     run = tiny.drive(cell)
     assert run.correct, [(c.name, c.value) for c in run.checks]
     assert run.attempted > 0 and run.failed == 0
+    chips = run.cell["chips"]
+    for leaf in run.extra["placement"]:
+        assert leaf["devices"] == chips, leaf["leaf"]
+        if chips > 1 and leaf["shape"] and (
+                leaf["leaf"].startswith("tweets") or "blk_" in leaf["leaf"]):
+            # the row, dst-block and doc-block partitions themselves
+            assert leaf["split"], leaf["leaf"]
+            assert leaf["shard_rows"] * chips == leaf["shape"][0], leaf
 
 
 def _alter_answers(drv, cfg):
@@ -34,8 +49,10 @@ def _alter_answers(drv, cfg):
     drv.Cell.__init__ = patched
 
 
-@pytest.mark.parametrize("cell", ANALYSIS_CELLS)
-def test_altered_answer_is_not_correct(cell):
+@pytest.mark.parametrize("cell", CELLS, ids=tiny.label)
+def test_altered_answer_is_not_correct(cell, request):
+    if tiny.elsewhere(request, cell):
+        return
     run = tiny.drive(cell, patch=_alter_answers)
     assert not run.correct
     assert {c.name for c in run.checks if not c.ok} == {"score_err"}
@@ -43,8 +60,9 @@ def test_altered_answer_is_not_correct(cell):
 
 def test_bfloat16_control_fails_the_analysis_limit():
     _, _, cfg, _ = tiny.cell_files("pulse-8m.mixed")
+    drv = common.driver(cfg)
     data = social.build_social_data(np.random.default_rng(4),
-                                    **dict(tiny.TINY_DATA, doc_tokens=(7, 15)))
+                                    **dict(drv.TINY_DATA, doc_tokens=(7, 15)))
     ref, low = Reference(data), Reference(data, rounding=bf16)
     rng = np.random.default_rng(5)
     for _ in range(3):

@@ -82,6 +82,19 @@ def test_moves_is_reported_by_every_cell_listed(metric):
     assert (common.BENCH / "metrics" / f"{metric['name']}.py").is_file()
 
 
+DRIVERS = sorted(p.stem for p in (common.BENCH / "drivers").glob("*.py"))
+
+
+@pytest.mark.parametrize("kind", DRIVERS)
+def test_every_driver_supplies_its_hooks(kind):
+    """Beside ``run``, each driver kind brings its tiny size, its count of
+    requests due in a window and its control, so that tiny runs, the
+    manifest's tail check and ``bench/control.py`` name no kind."""
+    drv = common.driver({"driver": kind})
+    for hook in ("run", "tiny", "due_in_window", "control"):
+        assert callable(getattr(drv, hook, None)), (kind, hook)
+
+
 @pytest.mark.parametrize("cell", CELLS, ids=[c["name"] for c in CELLS])
 def test_every_cell_reports_setup_another_and_a_layer(cell):
     e2e = [m["name"] for m in BENCH["end_to_end"] if reported(m, cell)]
@@ -110,12 +123,8 @@ TAIL = re.compile(r"_p(\d+)_")
 
 
 def _due_in_window(cell):
-    from bench import generator
-    traffic = common.traffic_of(cell)
-    s = BENCH["run_seconds"]
-    if common.config_of(BENCH, cell)["driver"] == "lm":
-        return len(generator.lm_lengths(traffic, s))
-    return len(generator.analysis_arrivals(traffic, s))
+    drv = common.driver(common.config_of(BENCH, cell))
+    return drv.due_in_window(common.traffic_of(cell), BENCH["run_seconds"])
 
 
 @pytest.mark.parametrize(
